@@ -21,7 +21,8 @@
 #                                serial baseline, one compile per bucket)
 #   throughput throughput_bench  end-to-end runner throughput: per-round
 #                                dispatch vs whole-epoch scan+prefetch vs
-#                                shard_map (forced 2-device subprocess)
+#                                shard_map (in-process on a TPU, forced
+#                                2-device subprocess on the CPU)
 #   chaos     chaos_bench        deterministic fault tolerance: serving
 #                                goodput under churn, breaker vs none,
 #                                node-kill degradation per scheme, and
@@ -55,6 +56,8 @@ def main() -> None:
                     help="epochs for the accuracy curves (CPU-sized)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro import compile_cache
+    compile_cache.enable()
 
     def want(name):
         return only is None or name in only
@@ -89,8 +92,9 @@ def main() -> None:
         accuracy_curves.main(experiment=2, epochs=args.epochs)
         sys.stdout.flush()
     if want("throughput"):
-        # runs in its own subprocess: the forced multi-device XLA flag must
-        # be set before jax initialises, which has already happened here
+        # in-process on an accelerator; on the CPU it re-executes with the
+        # forced multi-device XLA flag, which must be set before jax
+        # initialises (already the case here)
         from benchmarks import throughput_bench
         throughput_bench.main([])
         sys.stdout.flush()

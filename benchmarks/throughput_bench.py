@@ -12,11 +12,12 @@ system level, on the same registry runner every scheme uses:
 
 Timings are the MEDIAN of --reps runs of a --epochs training run (examples/s
 and rounds/s computed from the epoch geometry), after one unmeasured warmup
-run that absorbs compilation.  Run on a FORCED multi-device CPU host
+run that absorbs compilation.  On an accelerator it runs in-process over the
+real devices.  On the CPU it runs on a FORCED multi-device host
 (XLA_FLAGS=--xla_force_host_platform_device_count=2) so the shard_map path
-executes real collectives: the speedup is measured, not asserted.  When the
-current process was started without that flag the benchmark re-executes
-itself in a subprocess with it set (device count is frozen at jax init).
+executes real collectives: when the current process was started without
+that flag the benchmark re-executes itself in a subprocess with it set
+(device count is frozen at jax init).
 
 Results: stdout CSV + BENCH_throughput.json (tracked across PRs, consumed
 by the ROADMAP's measured-throughput entry).
@@ -87,6 +88,7 @@ def run(reps: int = 5, epochs: int = 2, batch: int = 32,
         "mesh": dict(mesh.shape), "epochs": epochs, "batch": batch,
         "rounds_per_epoch": rounds, "examples_per_epoch": examples,
         "reps": reps, "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
     }}
     print("variant,examples_per_sec,rounds_per_sec,sec_per_epoch,"
           "speedup_vs_per_round")
@@ -131,11 +133,17 @@ def main(argv=None):
     ap.add_argument("--scheme", default="inl")
     ap.add_argument("--json", default="BENCH_throughput.json")
     ap.add_argument("--devices", type=int, default=DEFAULT_DEVICES,
-                    help="forced host device count (re-exec if the current "
-                         "process was started without the XLA flag)")
+                    help="CPU only: forced host device count (re-exec if "
+                         "the current process was started without the XLA "
+                         "flag)")
     args = ap.parse_args(argv)
 
-    if DEVICE_FLAG not in os.environ.get("XLA_FLAGS", ""):
+    import jax
+    # on an accelerator the run stays in this process over the real
+    # devices: a child would find the chip held by this parent.  Only the
+    # CPU host re-executes with forced virtual devices
+    if (jax.default_backend() == "cpu"
+            and DEVICE_FLAG not in os.environ.get("XLA_FLAGS", "")):
         argv = argv if argv is not None else sys.argv[1:]
         rc = _reexec_with_devices(list(argv), args.devices)
         if rc:

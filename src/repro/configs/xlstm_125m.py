@@ -21,8 +21,12 @@ CONFIG = register(
         vocab_size=50_304,
         head_dim=192,
         block_pattern=_PATTERN,
+        # chunk_size is the mLSTM time scan's remat granularity, not a
+        # width: each in-chunk recompute holds chunk x (B, H, 384, 384) fp32
+        # matrix states, and at 256 the INL split's train step (J=4 nodes)
+        # does not fit one 16 GB v5e even at batch 1 x 1024 tokens
         ssm=SSMConfig(state_dim=64, conv_width=4, expand=2, head_dim=384,
-                      chunk_size=256),
+                      chunk_size=64),
         inl=INLConfig(num_nodes=4, encoder_layers=2, d_bottleneck=192),
         source="[arXiv:2405.04517]",
     ),

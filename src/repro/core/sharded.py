@@ -56,7 +56,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import linkfault, linkmodel, losses, paper_model, wirefmt
@@ -212,7 +212,7 @@ def make_inl_sharded_round(cfg, mesh, optimizer, *, wire: str = "dense",
             in_specs=(p_specs, c, P("client", "data"), P("data"),
                       P("client", "data"), P("data"), c, P()),
             out_specs=(p_specs, P(), c),
-            check_rep=False,
+            check_vma=False,
         )(params, mstate["encoders"], views, labels, eps, masks,
           jnp.asarray(gid_of_view, jnp.int32), fmask)
         new_params, new_opt = optimizer.update(grads, opt_state, params)
@@ -284,7 +284,7 @@ def make_fl_sharded_round(cfg, mesh, optimizer, local_steps: int, *,
         in_specs=(P("client"), P("client"), P("client"), P("client"),
                   P("client"), P("client"), P("client")),
         out_specs=(P("client"), P("client"), P("client"), P()),
-        check_rep=False)
+        check_vma=False)
 
     def round_fn(state, views, labels, rng):
         # identical packing to FLScheme.make_round's single-device path
@@ -365,7 +365,7 @@ def make_sl_sharded_round(cfg, mesh, opt_client, opt_server, *,
             local_grads, mesh=mesh,
             in_specs=(P(), P(), P(), P(None, "data"), P("data"), P("data")),
             out_specs=(P(), P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(state["client"], state["server"], state["state"], views, labels,
           masks)
         new_client, new_opt_c = opt_client.update(g_c, state["opt_c"],

@@ -11,7 +11,7 @@ TPU adaptation notes (vs the CUDA flash-attention algorithm):
     grid-size reduction per q tile via the kv upper bound, not warp-level
     control flow.
 
-Target: TPU (MXU 128x128, VMEM ~16 MB).  Validated with interpret=True on CPU
+Target: TPU (MXU 128x128, VMEM ~16 MB).  Validated in interpret mode on CPU
 against kernels/ref.py.
 """
 from __future__ import annotations
@@ -81,11 +81,14 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, seq_k: int,
                               "q_offset", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_offset: int = 0, block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K, interpret: bool = True):
+                    block_k: int = DEFAULT_BLOCK_K, interpret: bool = None):
     """q: (B, Sq, H, Dh); k, v: (B, Sk, KV, Dh).  Returns (B, Sq, H, Dh).
 
     Sq % block_q == 0 and Sk % block_k == 0 are required (pad upstream).
+    interpret=None compiles on TPU and interprets elsewhere.
     """
+    from repro.kernels import ops          # lazy: ops imports this module
+    interpret = ops.resolve_interpret(interpret)
     B, Sq, H, Dh = q.shape
     _, Sk, KV, _ = k.shape
     g = H // KV

@@ -38,6 +38,16 @@ Contract:
   * arbitrary leading dims; rows padded to a block_t multiple (no assert),
     outputs sliced back.  With learned priors, priors are (d,) shared or
     (J, d) per-node with mu shaped (J, ..., d).
+  * Mosaic tiling: inside the pallas_calls the per-row rate is laid out
+    lane-dense as (1, R) (learned priors: (J, 1, T)) and the priors as
+    (J, 1, d), so every block's last two dims are (8, 128)-divisible or
+    full; the wrappers restore the (...,) rate and (J, d) prior shapes.
+    Compiled on TPU, a block_t below the row count must be a multiple of
+    128 (the default 256 is; fewer rows use one full-size block).
+  * codewords are built as int32 (TPU has no float<->uint32 cast; a
+    codeword is below 2^16) and packed with lane masks and lane
+    reductions, no minor-dim reshape; the uint32 lanes are an XLA
+    bitcast outside the kernels.
   * `impl="reference"` routes the same custom VJP through the jnp oracle
     (kernels/ref.py), which XLA compiles to one fused pass on CPU — CI and
     TPU run identical code paths.
@@ -80,6 +90,18 @@ def _quantize(pre, bits: int):
     return jnp.round((jnp.clip(pre, -r, r) + r) * scale) / scale - r
 
 
+def _rate(u, mu, lv, mode: str):
+    """Per-row rate of one (rows, d) tile as a lane-dense (1, rows) row."""
+    if mode == "sample":
+        rate = 0.5 * jnp.sum(u * u - (u - mu) ** 2 * jnp.exp(-lv) - lv,
+                             axis=-1)
+    elif mode == "analytic":
+        rate = 0.5 * jnp.sum(jnp.exp(lv) + mu * mu - 1.0 - lv, axis=-1)
+    else:
+        rate = jnp.zeros(u.shape[:-1], jnp.float32)
+    return rate[None, :]
+
+
 def _cut_fwd_kernel(mu_ref, lv_ref, eps_ref, u_ref, rate_ref, *,
                     bits: int, mode: str):
     mu = mu_ref[...].astype(jnp.float32)
@@ -88,30 +110,65 @@ def _cut_fwd_kernel(mu_ref, lv_ref, eps_ref, u_ref, rate_ref, *,
     sigma = jnp.exp(0.5 * lv)
     u = _quantize(mu + sigma * eps, bits)
     u_ref[...] = u.astype(u_ref.dtype)
-    if mode == "sample":
-        rate = 0.5 * jnp.sum(u * u - (u - mu) ** 2 * jnp.exp(-lv) - lv,
-                             axis=-1)
-    elif mode == "analytic":
-        rate = 0.5 * jnp.sum(jnp.exp(lv) + mu * mu - 1.0 - lv, axis=-1)
-    else:
-        rate = jnp.zeros(u.shape[:-1], jnp.float32)
-    rate_ref[...] = rate.astype(rate_ref.dtype)
+    rate_ref[...] = _rate(u, mu, lv, mode).astype(rate_ref.dtype)
+
+
+def _codewords(x, bits: int):
+    """Codeword index of the link quantizer as int32 (same value as
+    ref.quantize_index, which is below 2^16)."""
+    r = ref.QUANT_RANGE
+    scale = ((1 << bits) - 1) / (2.0 * r)
+    return jnp.round((jnp.clip(x, -r, r) + r) * scale).astype(jnp.int32)
+
+
+def _dequantize(idx, bits: int):
+    r = ref.QUANT_RANGE
+    scale = ((1 << bits) - 1) / (2.0 * r)
+    return idx.astype(jnp.float32) / scale - r
+
+
+def _lane_group(lane, w: int, bits: int):
+    """Which lanes of a d-vector lane iota hold codewords of packed word w,
+    and each one's bit offset inside that word."""
+    vpw = 32 // bits
+    lo = w * vpw
+    grp = (lane >= lo) & (lane < lo + vpw)
+    return grp, jnp.where(grp, (lane - lo) * bits, 0)
 
 
 def _pack_lanes(idx, W: int, bits: int):
-    """(rows, d) uint32 codewords -> (rows, W) uint32 lanes, in-kernel.
+    """(rows, d) int32 codewords -> (rows, W) int32 words, in-kernel.
 
-    Same little-endian lane layout as ref.pack_indices; the iota is
-    broadcasted (TPU disallows 1-D iota inside kernels)."""
-    vpw = 32 // bits
+    Same little-endian lane layout as ref.pack_indices (the bits of the
+    int32 words are the uint32 lanes).  Each word is a masked lane
+    reduction of its shifted codewords; the bit fields are disjoint, so the
+    sum is their OR."""
     rows, d = idx.shape
-    pad = W * vpw - d
-    if pad:
-        idx = jnp.pad(idx, ((0, 0), (0, pad)))
-    grouped = idx.reshape(rows, W, vpw)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, vpw), 2) \
-        * jnp.uint32(bits)
-    return jnp.sum(grouped << shifts, axis=-1, dtype=jnp.uint32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 1)
+    word = jax.lax.broadcasted_iota(jnp.int32, (rows, W), 1)
+    packed = jnp.zeros((rows, W), jnp.int32)
+    for w in range(W):
+        grp, shift = _lane_group(lane, w, bits)
+        col = jnp.sum(jnp.where(grp, idx << shift, 0), axis=-1,
+                      keepdims=True)
+        packed = jnp.where(word == w, col, packed)
+    return packed
+
+
+def _unpack_lanes(packed, d: int, bits: int):
+    """(rows, W) int32 words -> (rows, d) int32 codewords, in-kernel."""
+    rows, W = packed.shape
+    mask = (1 << bits) - 1
+    word = jax.lax.broadcasted_iota(jnp.int32, (rows, W), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 1)
+    idx = jnp.zeros((rows, d), jnp.int32)
+    for w in range(W):
+        col = jnp.sum(jnp.where(word == w, packed, 0), axis=-1,
+                      keepdims=True)
+        grp, shift = _lane_group(lane, w, bits)
+        idx = jnp.where(grp, jax.lax.shift_right_logical(col, shift) & mask,
+                        idx)
+    return idx
 
 
 def _cut_fwd_pack_kernel(mu_ref, lv_ref, eps_ref, u_ref, pk_ref, rate_ref, *,
@@ -123,47 +180,24 @@ def _cut_fwd_pack_kernel(mu_ref, lv_ref, eps_ref, u_ref, pk_ref, rate_ref, *,
     lv = lv_ref[...].astype(jnp.float32)
     eps = eps_ref[...].astype(jnp.float32)
     sigma = jnp.exp(0.5 * lv)
-    pre = mu + sigma * eps
-    r = ref.QUANT_RANGE
-    scale = ((1 << bits) - 1) / (2.0 * r)
-    idx = jnp.round((jnp.clip(pre, -r, r) + r) * scale).astype(jnp.uint32)
-    u = idx.astype(jnp.float32) / scale - r
+    idx = _codewords(mu + sigma * eps, bits)
+    u = _dequantize(idx, bits)
     u_ref[...] = u.astype(u_ref.dtype)
     pk_ref[...] = _pack_lanes(idx, pk_ref.shape[-1], bits)
-    if mode == "sample":
-        rate = 0.5 * jnp.sum(u * u - (u - mu) ** 2 * jnp.exp(-lv) - lv,
-                             axis=-1)
-    elif mode == "analytic":
-        rate = 0.5 * jnp.sum(jnp.exp(lv) + mu * mu - 1.0 - lv, axis=-1)
-    else:
-        rate = jnp.zeros(u.shape[:-1], jnp.float32)
-    rate_ref[...] = rate.astype(rate_ref.dtype)
+    rate_ref[...] = _rate(u, mu, lv, mode).astype(rate_ref.dtype)
 
 
 def _pack_kernel(u_ref, pk_ref, *, bits: int):
     """Standalone pack: quantized values -> codeword lanes (used for paths
     whose forward kernel does not emit packed output, e.g. learned priors)."""
-    u = u_ref[...].astype(jnp.float32)
-    r = ref.QUANT_RANGE
-    scale = ((1 << bits) - 1) / (2.0 * r)
-    idx = jnp.round((jnp.clip(u, -r, r) + r) * scale).astype(jnp.uint32)
+    idx = _codewords(u_ref[...].astype(jnp.float32), bits)
     pk_ref[...] = _pack_lanes(idx, pk_ref.shape[-1], bits)
 
 
 def _unpack_dequant_kernel(pk_ref, u_ref, *, bits: int):
     """Fusion-center side: packed lanes -> dense quantized values."""
-    packed = pk_ref[...]
-    rows, W = packed.shape
-    d = u_ref.shape[-1]
-    vpw = 32 // bits
-    mask = jnp.uint32((1 << bits) - 1)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, vpw), 2) \
-        * jnp.uint32(bits)
-    ext = (packed[..., None] >> shifts) & mask
-    idx = ext.reshape(rows, W * vpw)[:, :d]
-    r = ref.QUANT_RANGE
-    scale = ((1 << bits) - 1) / (2.0 * r)
-    u_ref[...] = (idx.astype(jnp.float32) / scale - r).astype(u_ref.dtype)
+    idx = _unpack_lanes(pk_ref[...], u_ref.shape[-1], bits)
+    u_ref[...] = _dequantize(idx, bits).astype(u_ref.dtype)
 
 
 def _cut_bwd_kernel(mu_ref, lv_ref, eps_ref, gu_ref, gr_ref,
@@ -172,7 +206,7 @@ def _cut_bwd_kernel(mu_ref, lv_ref, eps_ref, gu_ref, gr_ref,
     lv = lv_ref[...].astype(jnp.float32)
     eps = eps_ref[...].astype(jnp.float32)
     gu = gu_ref[...].astype(jnp.float32)
-    gr = gr_ref[...].astype(jnp.float32)[:, None]
+    gr = gr_ref[0].astype(jnp.float32)[:, None]          # (1, rows) row
     sigma = jnp.exp(0.5 * lv)
     if mode == "sample":
         u = _quantize(mu + sigma * eps, bits)
@@ -194,19 +228,26 @@ def _cut_bwd_kernel(mu_ref, lv_ref, eps_ref, gu_ref, gr_ref,
     deps_ref[...] = deps.astype(deps_ref.dtype)
 
 
+def _rate_spec(block_t):
+    """The lane-dense (1, R) per-row rate, one (1, block_t) block per row
+    block."""
+    return pl.BlockSpec((1, block_t), lambda i: (0, i))
+
+
 def _fwd_pallas(mu, logvar, eps, bits, mode, block_t, interpret):
     R, d = mu.shape
     grid = (R // block_t,)
     spec = pl.BlockSpec((block_t, d), lambda i: (i, 0))
-    return pl.pallas_call(
+    u, rate = pl.pallas_call(
         functools.partial(_cut_fwd_kernel, bits=bits, mode=mode),
         grid=grid,
         in_specs=[spec, spec, spec],
-        out_specs=[spec, pl.BlockSpec((block_t,), lambda i: (i,))],
+        out_specs=[spec, _rate_spec(block_t)],
         out_shape=[jax.ShapeDtypeStruct((R, d), mu.dtype),
-                   jax.ShapeDtypeStruct((R,), jnp.float32)],
+                   jax.ShapeDtypeStruct((1, R), jnp.float32)],
         interpret=interpret,
     )(mu, logvar, eps)
+    return u, rate[0]
 
 
 def _bwd_pallas(mu, logvar, eps, gu, grate, bits, mode, block_t,
@@ -214,17 +255,16 @@ def _bwd_pallas(mu, logvar, eps, gu, grate, bits, mode, block_t,
     R, d = mu.shape
     grid = (R // block_t,)
     spec = pl.BlockSpec((block_t, d), lambda i: (i, 0))
-    spec1 = pl.BlockSpec((block_t,), lambda i: (i,))
     return pl.pallas_call(
         functools.partial(_cut_bwd_kernel, bits=bits, mode=mode),
         grid=grid,
-        in_specs=[spec, spec, spec, spec, spec1],
+        in_specs=[spec, spec, spec, spec, _rate_spec(block_t)],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((R, d), mu.dtype),
                    jax.ShapeDtypeStruct((R, d), logvar.dtype),
                    jax.ShapeDtypeStruct((R, d), eps.dtype)],
         interpret=interpret,
-    )(mu, logvar, eps, gu, grate)
+    )(mu, logvar, eps, gu, grate.reshape(1, R))
 
 
 def _fwd_pack_pallas(mu, logvar, eps, bits, mode, block_t, interpret):
@@ -232,31 +272,33 @@ def _fwd_pack_pallas(mu, logvar, eps, bits, mode, block_t, interpret):
     W = ref.packed_width(d, bits)
     grid = (R // block_t,)
     spec = pl.BlockSpec((block_t, d), lambda i: (i, 0))
-    return pl.pallas_call(
+    u, words, rate = pl.pallas_call(
         functools.partial(_cut_fwd_pack_kernel, bits=bits, mode=mode),
         grid=grid,
         in_specs=[spec, spec, spec],
         out_specs=[spec, pl.BlockSpec((block_t, W), lambda i: (i, 0)),
-                   pl.BlockSpec((block_t,), lambda i: (i,))],
+                   _rate_spec(block_t)],
         out_shape=[jax.ShapeDtypeStruct((R, d), mu.dtype),
-                   jax.ShapeDtypeStruct((R, W), jnp.uint32),
-                   jax.ShapeDtypeStruct((R,), jnp.float32)],
+                   jax.ShapeDtypeStruct((R, W), jnp.int32),
+                   jax.ShapeDtypeStruct((1, R), jnp.float32)],
         interpret=interpret,
     )(mu, logvar, eps)
+    return u, jax.lax.bitcast_convert_type(words, jnp.uint32), rate[0]
 
 
 def _pack_pallas(u, bits, block_t, interpret):
     R, d = u.shape
     W = ref.packed_width(d, bits)
     grid = (R // block_t,)
-    return pl.pallas_call(
+    words = pl.pallas_call(
         functools.partial(_pack_kernel, bits=bits),
         grid=grid,
         in_specs=[pl.BlockSpec((block_t, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_t, W), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, W), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((R, W), jnp.int32),
         interpret=interpret,
     )(u)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 def _unpack_pallas(packed, d, bits, dtype, block_t, interpret):
@@ -269,7 +311,7 @@ def _unpack_pallas(packed, d, bits, dtype, block_t, interpret):
         out_specs=pl.BlockSpec((block_t, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, d), dtype),
         interpret=interpret,
-    )(packed)
+    )(jax.lax.bitcast_convert_type(packed, jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -281,8 +323,8 @@ def _cut_prior_fwd_kernel(mu_ref, lv_ref, eps_ref, pmu_ref, plv_ref,
     mu = mu_ref[0].astype(jnp.float32)           # (block_t, d)
     lv = lv_ref[0].astype(jnp.float32)
     eps = eps_ref[0].astype(jnp.float32)
-    pmu = pmu_ref[...].astype(jnp.float32)       # (1, d) broadcasts over rows
-    plv = plv_ref[...].astype(jnp.float32)
+    pmu = pmu_ref[0].astype(jnp.float32)         # (1, d) broadcasts over rows
+    plv = plv_ref[0].astype(jnp.float32)
     sigma = jnp.exp(0.5 * lv)
     u = _quantize(mu + sigma * eps, bits)
     u_ref[0] = u.astype(u_ref.dtype)
@@ -292,7 +334,7 @@ def _cut_prior_fwd_kernel(mu_ref, lv_ref, eps_ref, pmu_ref, plv_ref,
     else:                                        # "analytic"
         rate = 0.5 * jnp.sum(plv - lv + (jnp.exp(lv) + (mu - pmu) ** 2)
                              * jnp.exp(-plv) - 1.0, axis=-1)
-    rate_ref[0] = rate.astype(rate_ref.dtype)
+    rate_ref[0] = rate[None, :].astype(rate_ref.dtype)
 
 
 def _cut_prior_bwd_kernel(mu_ref, lv_ref, eps_ref, pmu_ref, plv_ref,
@@ -302,10 +344,10 @@ def _cut_prior_bwd_kernel(mu_ref, lv_ref, eps_ref, pmu_ref, plv_ref,
     mu = mu_ref[0].astype(jnp.float32)
     lv = lv_ref[0].astype(jnp.float32)
     eps = eps_ref[0].astype(jnp.float32)
-    pmu = pmu_ref[...].astype(jnp.float32)       # (1, d)
-    plv = plv_ref[...].astype(jnp.float32)
+    pmu = pmu_ref[0].astype(jnp.float32)         # (1, d)
+    plv = plv_ref[0].astype(jnp.float32)
     gu = gu_ref[0].astype(jnp.float32)
-    gr = gr_ref[0].astype(jnp.float32)[:, None]
+    gr = gr_ref[0, 0].astype(jnp.float32)[:, None]
     sigma = jnp.exp(0.5 * lv)
     if mode == "sample":
         u = u_ref[0].astype(jnp.float32)         # saved forward output
@@ -336,46 +378,53 @@ def _cut_prior_bwd_kernel(mu_ref, lv_ref, eps_ref, pmu_ref, plv_ref,
     def _init():
         dpmu_ref[...] = jnp.zeros(dpmu_ref.shape, dpmu_ref.dtype)
         dplv_ref[...] = jnp.zeros(dplv_ref.shape, dplv_ref.dtype)
-    dpmu_ref[...] += dpmu.astype(dpmu_ref.dtype)
-    dplv_ref[...] += dplv.astype(dplv_ref.dtype)
+    dpmu_ref[0] += dpmu.astype(dpmu_ref.dtype)
+    dplv_ref[0] += dplv.astype(dplv_ref.dtype)
+
+
+# the prior grid's blocks: per-node priors travel as (J, 1, d) and the rate
+# as (J, 1, T), so each block's last two dims are full or lane-aligned
+def _prior_specs(block_t, d):
+    row = pl.BlockSpec((1, block_t, d), lambda j, i: (j, i, 0))
+    prior = pl.BlockSpec((1, 1, d), lambda j, i: (j, 0, 0))
+    rate = pl.BlockSpec((1, 1, block_t), lambda j, i: (j, 0, i))
+    return row, prior, rate
 
 
 def _prior_fwd_pallas(mu, logvar, eps, pmu, plv, bits, mode, block_t,
                       interpret):
     J, T, d = mu.shape
-    grid = (J, T // block_t)
-    row = pl.BlockSpec((1, block_t, d), lambda j, i: (j, i, 0))
-    prior = pl.BlockSpec((1, d), lambda j, i: (j, 0))
-    return pl.pallas_call(
+    row, prior, rate_spec = _prior_specs(block_t, d)
+    u, rate = pl.pallas_call(
         functools.partial(_cut_prior_fwd_kernel, bits=bits, mode=mode),
-        grid=grid,
+        grid=(J, T // block_t),
         in_specs=[row, row, row, prior, prior],
-        out_specs=[row, pl.BlockSpec((1, block_t), lambda j, i: (j, i))],
+        out_specs=[row, rate_spec],
         out_shape=[jax.ShapeDtypeStruct((J, T, d), mu.dtype),
-                   jax.ShapeDtypeStruct((J, T), jnp.float32)],
+                   jax.ShapeDtypeStruct((J, 1, T), jnp.float32)],
         interpret=interpret,
-    )(mu, logvar, eps, pmu, plv)
+    )(mu, logvar, eps, pmu.reshape(J, 1, d), plv.reshape(J, 1, d))
+    return u, rate.reshape(J, T)
 
 
 def _prior_bwd_pallas(mu, logvar, eps, pmu, plv, u, gu, grate, bits, mode,
                       block_t, interpret):
     J, T, d = mu.shape
-    grid = (J, T // block_t)
-    row = pl.BlockSpec((1, block_t, d), lambda j, i: (j, i, 0))
-    prior = pl.BlockSpec((1, d), lambda j, i: (j, 0))
-    rate = pl.BlockSpec((1, block_t), lambda j, i: (j, i))
-    return pl.pallas_call(
+    row, prior, rate_spec = _prior_specs(block_t, d)
+    dmu, dlv, deps, dpmu, dplv = pl.pallas_call(
         functools.partial(_cut_prior_bwd_kernel, bits=bits, mode=mode),
-        grid=grid,
-        in_specs=[row, row, row, prior, prior, row, row, rate],
+        grid=(J, T // block_t),
+        in_specs=[row, row, row, prior, prior, row, row, rate_spec],
         out_specs=[row, row, row, prior, prior],
         out_shape=[jax.ShapeDtypeStruct((J, T, d), mu.dtype),
                    jax.ShapeDtypeStruct((J, T, d), logvar.dtype),
                    jax.ShapeDtypeStruct((J, T, d), eps.dtype),
-                   jax.ShapeDtypeStruct((J, d), pmu.dtype),
-                   jax.ShapeDtypeStruct((J, d), plv.dtype)],
+                   jax.ShapeDtypeStruct((J, 1, d), pmu.dtype),
+                   jax.ShapeDtypeStruct((J, 1, d), plv.dtype)],
         interpret=interpret,
-    )(mu, logvar, eps, pmu, plv, u, gu, grate)
+    )(mu, logvar, eps, pmu.reshape(J, 1, d), plv.reshape(J, 1, d), u, gu,
+      grate.reshape(J, 1, T))
+    return dmu, dlv, deps, dpmu.reshape(J, d), dplv.reshape(J, d)
 
 
 # ---------------------------------------------------------------------------
@@ -445,10 +494,8 @@ _cutlayer_prior.defvjp(_cutlayer_prior_fwd, _cutlayer_prior_bwd)
 # ---------------------------------------------------------------------------
 
 def _resolve_interpret(interpret):
-    if interpret is not None:
-        return interpret
     from repro.kernels import ops          # lazy: ops imports this module
-    return not ops.on_tpu()
+    return ops.resolve_interpret(interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("link_bits", "rate_estimator",

@@ -16,9 +16,9 @@ split in one backward pass, under a single shared `jax.custom_vjp`.  Both
 backends run that same VJP wrapper — "reference" swaps the kernel bodies
 for the jnp oracle so CPU CI exercises the training code path exactly.
 
-`resolve_backend` / `on_tpu` are the canonical resolvers; kernel modules
-use them for their `interpret=None` auto-detection (a kernel must never
-silently interpret on TPU, nor compile Mosaic on CPU).
+`resolve_backend` / `resolve_interpret` are the canonical resolvers; kernel
+modules use the latter for their `interpret=None` auto-detection (a kernel
+must never silently interpret on TPU, nor compile Mosaic on CPU).
 """
 from __future__ import annotations
 
@@ -34,7 +34,10 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-_on_tpu = on_tpu                      # back-compat alias
+def resolve_interpret(interpret):
+    """A kernel's `interpret=None`: compiled on TPU, interpret mode
+    elsewhere."""
+    return (not on_tpu()) if interpret is None else interpret
 
 
 def resolve_backend(backend: str) -> str:
@@ -45,15 +48,11 @@ def resolve_backend(backend: str) -> str:
     return backend
 
 
-_resolve = resolve_backend            # back-compat alias
-
-
 def attention(q, k, v, *, causal=True, window=0, q_offset=0,
               backend: str = "auto", **block_kw):
     if resolve_backend(backend) == "pallas":
         return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                                   q_offset=q_offset,
-                                   interpret=not on_tpu(), **block_kw)
+                                   q_offset=q_offset, **block_kw)
     return ref.attention_ref(q, k, v, causal=causal, window=window,
                              q_offset=q_offset)
 
@@ -61,8 +60,7 @@ def attention(q, k, v, *, causal=True, window=0, q_offset=0,
 def bottleneck(mu, logvar, eps, *, backend: str = "auto", **block_kw):
     """Seed-compatible fused sample + analytic KL (no quantizer)."""
     if resolve_backend(backend) == "pallas":
-        return _bn.bottleneck_fused(mu, logvar, eps,
-                                    interpret=not on_tpu(), **block_kw)
+        return _bn.bottleneck_fused(mu, logvar, eps, **block_kw)
     return ref.bottleneck_ref(mu, logvar, eps)
 
 
@@ -99,6 +97,5 @@ def cutlayer(mu, logvar, eps, *, link_bits: int = 32,
 
 def ssd_scan(x, dt, a, bm, cm, dskip, *, backend: str = "auto", **block_kw):
     if resolve_backend(backend) == "pallas":
-        return _ssd.ssd_scan(x, dt, a, bm, cm, dskip,
-                             interpret=not on_tpu(), **block_kw)
+        return _ssd.ssd_scan(x, dt, a, bm, cm, dskip, **block_kw)
     return ref.ssd_scan_ref(x, dt, a, bm, cm, dskip)

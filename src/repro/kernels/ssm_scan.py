@@ -72,13 +72,16 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, state_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt, a, bm, cm, dskip, *, chunk: int = DEFAULT_CHUNK,
-             interpret: bool = True):
+             interpret: bool = None):
     """Chunked SSD scan.
 
     x: (B, S, H, P); dt: (B, S, H) post-softplus; a: (H,) negative;
     bm, cm: (B, S, N) shared across heads (ngroups=1); dskip: (H,).
-    Returns y: (B, S, H, P).  S % chunk == 0 required.
+    Returns y: (B, S, H, P).  S % chunk == 0 required.  interpret=None
+    compiles on TPU and interprets elsewhere.
     """
+    from repro.kernels import ops          # lazy: ops imports this module
+    interpret = ops.resolve_interpret(interpret)
     B, S, H, P = x.shape
     N = bm.shape[-1]
     chunk = min(chunk, S)

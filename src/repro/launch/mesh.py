@@ -35,20 +35,9 @@ def _client_axis_size(num_clients: int, slots: int) -> int:
 
 
 def current_abstract_mesh():
-    """The ambient abstract mesh, or None when no mesh is active.
-
-    `jax.sharding.get_abstract_mesh` is only public from jax >= 0.5; on the
-    pinned 0.4.x it lives in `jax._src.mesh` and returns a non-mesh sentinel
-    when nothing is set.  Callers branch on None instead of `.empty` so both
-    versions work."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        from jax._src import mesh as _mesh
-        get = _mesh.get_abstract_mesh
-    m = get()
-    if not hasattr(m, "axis_names") or getattr(m, "empty", False):
-        return None
-    return m
+    """The ambient abstract mesh, or None when no mesh is active."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.data import tokens as token_data
 from repro.launch import steps as steps_lib
@@ -361,6 +362,7 @@ def main():
                          "Rejected result instead of growing latency "
                          "without limit")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.arch == "paper-inl":
         (serve_inl_loadgen if args.load_gen else serve_inl)(args)

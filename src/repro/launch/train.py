@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import checkpoint, optim
+from repro import checkpoint, compile_cache, optim
 from repro.configs import get_config, get_smoke_config
 from repro.core import inl_llm
 from repro.data import prefetch
@@ -29,7 +29,15 @@ from repro.data import tokens as token_data
 from repro.launch import steps as steps_lib
 
 
-def main():
+def make_optimizer(lr: float, steps: int):
+    """The driver's AdamW: warmup over the first tenth of `steps`, cosine
+    decay after, decoupled weight decay and global-norm clipping."""
+    return optim.adamw(
+        optim.warmup_cosine_schedule(lr, max(steps // 10, 1), steps),
+        weight_decay=0.1, clip_norm=1.0)
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -62,17 +70,15 @@ def main():
     ap.add_argument("--log-every", type=int, default=10,
                     help="(superseded: metrics are logged once per scan "
                          "group, i.e. every --scan-steps steps)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     import dataclasses
     cfg = dataclasses.replace(cfg, dtype="float32") if args.smoke else cfg
 
     key = jax.random.PRNGKey(args.seed)
-    optimizer = optim.adamw(
-        optim.warmup_cosine_schedule(args.lr, max(args.steps // 10, 1),
-                                     args.steps), weight_decay=0.1,
-        clip_norm=1.0)
+    optimizer = make_optimizer(args.lr, args.steps)
 
     if args.scheme == "inl":
         from repro.models import transformer

@@ -50,6 +50,30 @@ def test_flash_attention_window(window):
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("kernel", ["flash_attention", "ssd_scan"])
+def test_interpret_none_interprets_off_tpu(kernel):
+    """interpret=None resolves through kernels/ops: interpret mode on this
+    CPU host (compiling Mosaic here would raise), compiled on a TPU."""
+    from repro.kernels import ops
+    assert ops.resolve_interpret(None) is (jax.default_backend() != "tpu")
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    if kernel == "flash_attention":
+        q, k, v = (jax.random.normal(kk, (1, 64, 2, 32)) for kk in ks[:3])
+        out = flash_attention(q, k, v, causal=True, interpret=None)
+        want = ref.attention_ref(q, k, v, causal=True)
+    else:
+        B, S, H, P, N = 1, 32, 2, 16, 8
+        x = jax.random.normal(ks[0], (B, S, H, P))
+        dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+        a = -jnp.exp(jax.random.normal(ks[2], (H,)))
+        bm = jax.random.normal(ks[3], (B, S, N))
+        cm = jax.random.normal(ks[4], (B, S, N))
+        d = jax.random.normal(ks[5], (H,))
+        out = ssd_scan(x, dt, a, bm, cm, d, chunk=16, interpret=None)
+        want = ref.ssd_scan_ref(x, dt, a, bm, cm, d)
+    np.testing.assert_allclose(out, want, atol=5e-4, rtol=1e-4)
+
+
 def test_flash_attention_q_offset():
     """Chunked prefill: q at positions [64:128) attending to k[0:128)."""
     B, S, H, KV, Dh = 1, 128, 2, 2, 32
