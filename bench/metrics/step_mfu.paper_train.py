@@ -1,0 +1,11 @@
+"""The whole training step's share of the chips' bf16 peak: the model's
+forward and backward FLOPs per example (bench/flops.py, recomputation not
+counted) times examples per second, over chips times peak."""
+
+
+def read(run, out):
+    if run.peaks is None:
+        return None
+    f = out.facts
+    return 100.0 * f["train_flops_per_example"] * f["examples_per_s"] / (
+        len(run.devices) * run.peaks["bf16_flops_per_s"])
