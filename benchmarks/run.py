@@ -19,10 +19,6 @@
 #                                vs Poisson offered load per topology/wire
 #                                (asserted: continuous batching >= 2x the
 #                                serial baseline, one compile per bucket)
-#   throughput throughput_bench  end-to-end runner throughput: per-round
-#                                dispatch vs whole-epoch scan+prefetch vs
-#                                shard_map (in-process on a TPU, forced
-#                                2-device subprocess on the CPU)
 #   chaos     chaos_bench        deterministic fault tolerance: serving
 #                                goodput under churn, breaker vs none,
 #                                node-kill degradation per scheme, and
@@ -38,7 +34,6 @@
 #                                frontier beats the pure baselines at >= 1
 #                                bandwidth budget, closed == measured bits
 #                                on every trained point)
-#   roofline  roofline_report    dry-run three-term roofline rows
 from __future__ import annotations
 
 import argparse
@@ -50,8 +45,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="comma list: table1,curves,kernels,wire,topology,"
-                         "links,serve,throughput,chaos,cluster,frontier,"
-                         "roofline")
+                         "links,serve,chaos,cluster,frontier")
     ap.add_argument("--epochs", type=int, default=3,
                     help="epochs for the accuracy curves (CPU-sized)")
     args = ap.parse_args()
@@ -91,13 +85,6 @@ def main() -> None:
         from benchmarks import accuracy_curves
         accuracy_curves.main(experiment=2, epochs=args.epochs)
         sys.stdout.flush()
-    if want("throughput"):
-        # in-process on an accelerator; on the CPU it re-executes with the
-        # forced multi-device XLA flag, which must be set before jax
-        # initialises (already the case here)
-        from benchmarks import throughput_bench
-        throughput_bench.main([])
-        sys.stdout.flush()
     if want("chaos"):
         from benchmarks import chaos_bench
         chaos_bench.main(["--smoke", "--json", ""])
@@ -111,9 +98,6 @@ def main() -> None:
         from benchmarks import frontier_bench
         frontier_bench.main(["--smoke", "--json", "BENCH_frontier.json"])
         sys.stdout.flush()
-    if want("roofline"):
-        from benchmarks import roofline_report
-        roofline_report.main()
     print(f"# benchmarks done in {time.time()-t0:.1f}s")
 
 

@@ -171,33 +171,45 @@ def loss_fn(params: INLParams, state, views, labels, rng, cfg, *,
     params_c = paper_model.cast_compute(params, dt)
     views = views.astype(dt)
     r_enc, r_dec = jax.random.split(rng)
-    (mu, logvar), new_enc = _encode_mu_logvar(params_c, state, views,
-                                              train=train)
+    # named scopes, disjoint, so device time splits by the paper's layers
+    # (backward ops inherit them as transpose(jvp(<scope>))).  The cut
+    # layer's kernel call stays outside every scope: a scope would rename
+    # its instruction (jvp_jit__cutlayer_call__ becomes _cutlayer_call),
+    # and a trace finds the kernel by that name (jit(_cutlayer_call) in
+    # its op_name marks it as the cut's).
+    with jax.named_scope("encoder"):
+        (mu, logvar), new_enc = _encode_mu_logvar(params_c, state, views,
+                                                  train=train)
+    with jax.named_scope("cut"):
+        eps = jax.random.normal(r_enc, mu.shape, jnp.float32)
     if topo is None:
         u, rate, u_joint = wirefmt.cut_and_ship(
-            r_enc, mu, logvar, link_bits=cfg.link_bits,
+            None, mu, logvar, link_bits=cfg.link_bits,
             rate_estimator=rate_estimator, wire=wire, prior=params_c.priors,
-            backend=backend)
+            eps=eps, backend=backend)
     else:
-        eps = jax.random.normal(r_enc, mu.shape, jnp.float32)
         u, rate, u_joint = topology_lib.graph_cut_and_ship(
             topo, cfg, mu, logvar, eps, rate_estimator=rate_estimator,
             wire=wire, prior=params_c.priors, backend=backend)
-    if delivery is not None:
-        u_joint = linkfault.partial_fuse(u_joint, delivery)
-    elif faulty:
-        mask = linkfault.round_delivery_mask(rng, topo_full, cfg,
-                                             labels.shape[0], train=train)
-        u_joint = linkfault.partial_fuse(u_joint, mask)
+    with jax.named_scope("cut"):
+        if delivery is not None:
+            u_joint = linkfault.partial_fuse(u_joint, delivery)
+        elif faulty:
+            mask = linkfault.round_delivery_mask(rng, topo_full, cfg,
+                                                 labels.shape[0],
+                                                 train=train)
+            u_joint = linkfault.partial_fuse(u_joint, mask)
     new_state = {"encoders": new_enc}
-    joint, branch = decode(params_c, u, train=train, rng=r_dec,
-                           u_joint=u_joint)
+    with jax.named_scope("decoder"):
+        joint, branch = decode(params_c, u, train=train, rng=r_dec,
+                               u_joint=u_joint)
     J = u.shape[0]
-    loss, metrics = losses.inl_loss(
-        joint, list(branch), labels,
-        list(mu), list(logvar), list(u),
-        s=cfg.s, rate_estimator=rate_estimator, rates=list(rate))
-    metrics["accuracy"] = losses.accuracy(joint, labels)
+    with jax.named_scope("loss"):
+        loss, metrics = losses.inl_loss(
+            joint, list(branch), labels,
+            list(mu), list(logvar), list(u),
+            s=cfg.s, rate_estimator=rate_estimator, rates=list(rate))
+        metrics["accuracy"] = losses.accuracy(joint, labels)
     # §III-C accounting: activations forward + error vectors backward
     # (per-edge payloads summed when a topology re-routes them)
     if topo is None:
@@ -226,7 +238,9 @@ def make_train_step(cfg, optimizer, *, rate_estimator: str = "sample",
                     params, state, views, labels, rng, cfg, train=True,
                     rate_estimator=rate_estimator, wire=wire,
                     topology=topology, delivery=delivery)
-            new_params, new_opt = optimizer.update(grads, opt_state, params)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = optimizer.update(grads, opt_state,
+                                                       params)
             return new_params, new_state, new_opt, metrics
         return step_d
 
@@ -236,7 +250,8 @@ def make_train_step(cfg, optimizer, *, rate_estimator: str = "sample",
             loss_fn, has_aux=True)(params, state, views, labels, rng, cfg,
                                    train=True, rate_estimator=rate_estimator,
                                    wire=wire, topology=topology)
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, opt_state, params)
         return new_params, new_state, new_opt, metrics
     return step
 

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint as checkpoint_lib
+from repro import tracing
 from repro.core import bandwidth, linkfault
 from repro.core import topology as topology_lib
 from repro.core.schemes import base
@@ -284,11 +285,14 @@ def run_scheme(name: str, views, labels, cfg, *, epochs: int,
             rng, subs = _split_chain(rng, rounds)
             if ep < start_ep:
                 continue
-            idx = np.stack(list(multiview.batch_indices(
-                n, batch_size, seed=ep)))
-            idx = idx[:rounds * bpr].reshape(rounds, bpr, batch_size)
-            yield (np.moveaxis(views_np[:, idx], 0, 2), labels_np[idx],
-                   subs)
+            with tracing.span("runner.assemble", rounds=rounds) as sp:
+                idx = np.stack(list(multiview.batch_indices(
+                    n, batch_size, seed=ep)))
+                idx = idx[:rounds * bpr].reshape(rounds, bpr, batch_size)
+                item = (np.moveaxis(views_np[:, idx], 0, 2),
+                        labels_np[idx], subs)
+                sp.set_metadata(bytes=prefetch.nbytes(item))
+            yield item
 
     charges = _round_charges(scheme, cfg, state, batch_size, wire=wire,
                              topology=topology)
@@ -330,8 +334,8 @@ def _run_per_round(scheme, views, labels, cfg, *, epochs, batch_size, lr,
                    seed, eval_n, wire="dense", topology=None, meter=None,
                    ckpt_dir=None, ckpt_every: int = 1, resume: bool = False):
     """The seed-style path: one transfer + one jitted dispatch per round.
-    Kept verbatim as the throughput baseline (benchmarks/throughput_bench)
-    and the semantics reference the scan path is tested against."""
+    Kept verbatim as the semantics reference the scan path is tested
+    against."""
     state = scheme.init(cfg, jax.random.PRNGKey(seed), lr=lr)
     round_fn = scheme.make_round(cfg, lr=lr, wire=wire, topology=topology)
     bpr = scheme.batches_per_round(cfg)

@@ -24,8 +24,15 @@ from typing import Any, Iterable, Iterator
 
 import jax
 
+from repro import tracing
+
 # queue sentinels: exhaustion vs producer fault (the exception rides along)
 _DONE = object()
+
+
+def nbytes(item) -> int:
+    """Bytes of a pytree of arrays (host or device)."""
+    return sum(x.nbytes for x in jax.tree.leaves(item))
 
 
 class _Failure:
@@ -57,9 +64,12 @@ def prefetch_to_device(iterator: Iterable, *, size: int = 2,
         raise ValueError(f"prefetch size must be >= 1, got {size}")
 
     def _put(item):
-        if shardings is None:
-            return jax.device_put(item)
-        return jax.device_put(item, shardings)
+        # device_put returns before the bytes reach the device; the span
+        # does not wait for them, as waiting would end the overlap
+        with tracing.span("prefetch.put", bytes=nbytes(item)):
+            if shardings is None:
+                return jax.device_put(item)
+            return jax.device_put(item, shardings)
 
     # maxsize bounds host+device memory: at most `size` items buffered plus
     # the one the producer is transferring
@@ -99,7 +109,8 @@ def prefetch_to_device(iterator: Iterable, *, size: int = 2,
     thread.start()
     try:
         while True:
-            got = buf.get()
+            with tracing.span("prefetch.wait"):
+                got = buf.get()
             if got is _DONE:
                 return
             if isinstance(got, _Failure):

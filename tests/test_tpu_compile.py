@@ -124,3 +124,40 @@ def test_serving_bucket_1_compiles(one_chip):
     """The smallest serving bucket: J=5 views of one request, 5 rows."""
     x = _spec(one_chip, (5, 1, PAPER[1]))
     _compile(_fused("none"), x, x, x)
+
+
+def test_inl_loss_keeps_the_cut_kernels_instruction_names(one_chip,
+                                                         monkeypatch):
+    """The INL loss's named scopes (core/inl.py) leave the cut layer's
+    kernels named as a profiler trace shows them and the cut-layer reader
+    (bench/metrics/cutlayer_us_per_round.paper_train.py) finds them: a
+    scope around their call would rename them `_cutlayer_call`."""
+    import re
+
+    from repro.configs.paper_inl import PaperExperimentConfig
+    from repro.core import inl
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = PaperExperimentConfig(conv_channels=(8,), d_bottleneck=PAPER[1],
+                                dense_units=(32,), image_shape=(8, 8, 3))
+    B = PAPER[0] // cfg.num_clients
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
+                            tree)
+    params, state = on_chip(jax.eval_shape(
+        lambda k: inl.init(cfg, k), jax.random.PRNGKey(0)))
+    views = _spec(one_chip, (cfg.num_clients, B) + cfg.image_shape)
+    labels = _spec(one_chip, (B,), jnp.int32)
+    rng = _spec(one_chip, (2,), jnp.uint32)
+
+    def loss(*args):
+        return inl.loss_fn(*args, cfg)[0]
+    text = jax.jit(jax.grad(loss)).lower(
+        params, state, views, labels, rng).compile().as_text()
+    kernels = sorted(
+        re.sub(r"\.\d+$", "", line.split(" = ")[0].strip())
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+    assert kernels == ["%jvp_jit__cutlayer_call__",
+                       "%transpose_jvp_jit__cutlayer_call___"]
