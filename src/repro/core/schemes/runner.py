@@ -9,17 +9,20 @@ Dispatch strategies (the perf ladder tests/benchmarks compare):
 
     "per_round"  the seed-style loop: one host->device transfer + one jitted
                  dispatch per round (kept as the benchmark baseline);
-    "scan"       the default: the whole epoch's rounds are stacked host-side
-                 into ONE (K, R, ...) superbatch, moved through the
-                 double-buffered prefetcher (data/prefetch.py), and executed
-                 as ONE jitted lax.scan (Scheme.make_epoch) — K rounds per
-                 dispatch instead of K dispatches.
+    "scan"       the default: the view set is placed on the device once,
+                 each epoch's (K, R, b) index matrix, labels and round keys
+                 move through the double-buffered prefetcher
+                 (data/prefetch.py), ONE jitted gather on the device builds
+                 the epoch's (K, R, J, b, ...) superbatch from the resident
+                 set, and ONE jitted lax.scan (Scheme.make_epoch) runs it —
+                 K rounds per dispatch instead of K dispatches.
 
 `mesh` (a ('client', 'data') mesh from launch.mesh.make_inl_host_mesh /
 make_inl_mesh) switches the scan body to the scheme's shard_map round
 (core/sharded.py): J node branches in parallel over 'client', batch over
-'data', state placed once via Scheme.state_shardings and batches device_put
-pre-sharded by the prefetcher.  Trajectories match the single-device run at
+'data', state placed once via Scheme.state_shardings, the resident view set
+replicated and each epoch's superbatch gathered straight into the batch
+sharding.  Trajectories match the single-device run at
 rtol 1e-4 (tests/test_sharded_parity.py).
 """
 from __future__ import annotations
@@ -30,6 +33,7 @@ from typing import List, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import checkpoint as checkpoint_lib
 from repro import tracing
@@ -55,6 +59,32 @@ def _split_chain(key, n: int):
         k, sub = jax.random.split(k)
         return k, sub
     return jax.lax.scan(body, key, None, length=n)
+
+
+@partial(jax.jit, static_argnums=1)
+def _resident(views, n_eval: int):
+    """(J, n, ...) views -> the resident (J, n, prod(...)) rows, one row per
+    view, and the (J, n_eval, ...) evaluation slice.  Relaid one node's
+    views at a time: a TPU keeps a (J, n, 32, 32, 3) set with the sample
+    axis fastest, and relaying it whole needs temporaries of 2.6x the set."""
+    rows = jax.lax.map(lambda v: v.reshape(v.shape[0], -1), views)
+    return rows, views[:, :n_eval]
+
+
+def _superbatch(rows, idx, *, image_shape):
+    """The epoch's scan input from the resident (J, n, D) rows at the
+    (K, R, b) index matrix: (K, R, J, b) + image_shape, an exact copy of
+    `np.moveaxis(views[:, idx], 0, 2)`.  Whole rows are gathered, one
+    round group at a time (lax.map), so each group's rows are relaid into
+    the output in on-chip memory rather than through a copy of the epoch."""
+    J, n, _ = rows.shape
+    flat = rows.reshape(J * n, -1)
+    offsets = (jnp.arange(J, dtype=idx.dtype) * n)[:, None]
+
+    def group(ix):                     # (R, b) -> (R, J, b) + image_shape
+        return flat[ix[:, None] + offsets].reshape(
+            ix.shape[:1] + (J,) + ix.shape[1:] + image_shape)
+    return jax.lax.map(group, idx)
 
 
 def _round_charges(scheme, cfg, state, batch_size, *, wire, topology):
@@ -199,9 +229,12 @@ def run_scheme(name: str, views, labels, cfg, *, epochs: int,
     links (pass `meter=` a BandwidthMeter to read the per-edge ledgers
     afterwards).
 
-    dispatch="scan" (default) runs each epoch as one jitted lax.scan fed by
-    the device prefetcher; dispatch="per_round" keeps the seed-style loop
-    (one dispatch per round).  `mesh` enables shard_map execution (scan
+    dispatch="scan" (default) runs each epoch as one jitted lax.scan.  It
+    keeps the view set resident on the device (replicated over `mesh`) and
+    gathers each epoch's superbatch there from the prefetched index matrix,
+    so its device memory is the views' bytes plus one epoch superbatch;
+    dispatch="per_round" (the seed-style loop, one dispatch per round)
+    streams sets larger than that.  `mesh` enables shard_map execution (scan
     dispatch only).  wire="packed" moves the cut-layer collectives as
     bit-packed codewords (trajectories identical to dense);
     "packed_duplex" packs the backward error vectors too.  topology — a
@@ -250,17 +283,32 @@ def run_scheme(name: str, views, labels, cfg, *, epochs: int,
     epoch_fn = scheme.make_epoch(cfg, lr=lr, mesh=mesh, wire=wire,
                                  topology=topology)
     bpr = scheme.batches_per_round(cfg)
-    views_np, labels_np = np.asarray(views), np.asarray(labels)
+    labels_np = np.asarray(labels)
     n = labels_np.shape[0]
     rounds = rounds_per_epoch(scheme, cfg, n, batch_size)
+    n_eval = min(eval_n, n)
 
-    xs_shardings = None
+    with tracing.span("runner.resident") as sp:
+        resident, ev = _resident(views, n_eval)
+        if mesh is not None:
+            resident = jax.device_put(resident, NamedSharding(mesh, P()))
+        sp.set_metadata(bytes=resident.nbytes)
+    # one epoch's (K, R, J, b, ...) superbatch, in bytes
+    superbatch_bytes = resident.nbytes // resident.shape[1] * (
+        rounds * bpr * batch_size)
+
+    xs_shardings = item_shardings = None
     if mesh is not None:
         from repro.launch import sharding as sharding_lib
         state = jax.device_put(state,
                                scheme.state_shardings(cfg, state, mesh))
         xs_shardings = sharding_lib.scheme_batch_shardings(
             mesh, cfg.num_clients, batch_size)
+        # the index matrix is laid out as the labels are
+        item_shardings = (xs_shardings[1],) + xs_shardings[1:]
+    gather = jax.jit(_superbatch, static_argnames="image_shape",
+                     out_shardings=None if mesh is None else xs_shardings[0])
+    image_shape = tuple(views.shape[2:])
 
     meter = bandwidth.BandwidthMeter() if meter is None else meter
     start_ep = 0
@@ -273,13 +321,13 @@ def run_scheme(name: str, views, labels, cfg, *, epochs: int,
         curve0 = []
 
     def epoch_items():
-        """(views (K,R,J,b,...), labels (K,R,b), rngs (K,2)) per epoch —
-        the whole-epoch scan xs, assembled host-side (ONE gather over the
-        epoch's index matrix, not per-batch stacking) so the prefetcher can
-        overlap assembly + transfer with the previous epoch's compute.
-        A resumed run fast-forwards the rng chain through the completed
-        epochs WITHOUT assembling their batches — the downstream subkeys
-        (and so the trajectory) are exactly the uninterrupted run's."""
+        """(index matrix (K,R,b), labels (K,R,b), rngs (K,2)) per epoch —
+        what the device needs to gather the epoch's superbatch from the
+        resident views; the prefetcher moves it while the previous epoch
+        computes.  A resumed run fast-forwards the rng chain through the
+        completed epochs WITHOUT assembling their items — the downstream
+        subkeys (and so the trajectory) are exactly the uninterrupted
+        run's."""
         rng = jax.random.PRNGKey(seed + 1)
         for ep in range(epochs):
             rng, subs = _split_chain(rng, rounds)
@@ -288,9 +336,9 @@ def run_scheme(name: str, views, labels, cfg, *, epochs: int,
             with tracing.span("runner.assemble", rounds=rounds) as sp:
                 idx = np.stack(list(multiview.batch_indices(
                     n, batch_size, seed=ep)))
-                idx = idx[:rounds * bpr].reshape(rounds, bpr, batch_size)
-                item = (np.moveaxis(views_np[:, idx], 0, 2),
-                        labels_np[idx], subs)
+                idx = idx[:rounds * bpr].reshape(
+                    rounds, bpr, batch_size).astype(np.int32)
+                item = (idx, labels_np[idx], subs)
                 sp.set_metadata(bytes=prefetch.nbytes(item))
             yield item
 
@@ -298,18 +346,22 @@ def run_scheme(name: str, views, labels, cfg, *, epochs: int,
                              topology=topology)
     topo_full = topology_lib.resolve(topology, cfg)
     faulty = linkfault.active(topo_full, cfg, train=True)
-    n_eval = min(eval_n, n)
-    ev = jnp.asarray(views_np[:, :n_eval])
     el = jnp.asarray(labels_np[:n_eval])
 
     curve: List[CurvePoint] = list(curve0)
     items = prefetch.prefetch_to_device(
         epoch_items() if rounds else iter(()), size=prefetch_size,
-        shardings=xs_shardings)
+        shardings=item_shardings)
     for ep in range(start_ep, epochs):
         if rounds:
-            ep_views, ep_labels, ep_rngs = next(items)
+            ep_idx, ep_labels, ep_rngs = next(items)
+            with tracing.span("runner.gather", rounds=rounds,
+                              bytes=superbatch_bytes):
+                ep_views = gather(resident, ep_idx, image_shape=image_shape)
             state, _ = epoch_fn(state, ep_views, ep_labels, ep_rngs)
+            # freed once the epoch program is done with it, so the next
+            # gather finds at most this one superbatch beside the views
+            del ep_views
             if faulty:
                 # the scan's per-round subkeys ARE the round rngs — replay
                 # their folded fault draws host-side for the two ledgers

@@ -6,7 +6,8 @@ at the shapes the chip runs: the paper model's 1,280-row cut (J=5 x batch
 256, d_b=64), the xLSTM INL split's 4,096-row cut at d_b=192, and the
 serving plane's 5-row bucket.  Each compiled program must hold the Pallas
 kernel (`tpu_custom_call`).  Nothing runs, so results are checked by the
-interpret-mode tests (test_cutlayer_vjp.py, test_wireformat.py).
+interpret-mode tests (test_cutlayer_vjp.py, test_wireformat.py).  The
+scan runner's device gather is compiled here too, for its memory.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and the test workers must all
@@ -161,3 +162,26 @@ def test_inl_loss_keeps_the_cut_kernels_instruction_names(one_chip,
         if 'custom_call_target="tpu_custom_call"' in line)
     assert kernels == ["%jvp_jit__cutlayer_call__",
                        "%transpose_jvp_jit__cutlayer_call___"]
+
+
+def test_runner_device_gather_fits_the_chip(one_chip):
+    """The scan runner's resident view set and per-epoch gather
+    (core/schemes/runner.py) at the paper_inl_train cell's shapes: 40,960
+    views per node, 160 rounds of 256.  The superbatch must come out at its
+    unpadded 2,516,582,400 bytes, and neither the gather nor the set-up
+    relayout may need a padded copy of the set: a TPU keeps a
+    (..., 32, 32, 3) array with its leading axis fastest, and a gather that
+    took it as it is would need temporaries of 7.5x the superbatch."""
+    from repro.core.schemes import runner
+    J, n, image = 5, 40960, (32, 32, 3)
+    set_bytes = J * n * 32 * 32 * 3 * 4
+    rows = runner._resident.lower(
+        _spec(one_chip, (J, n) + image), 512).compile().memory_analysis()
+    assert rows.temp_size_in_bytes < 0.5 * set_bytes
+    gather = jax.jit(runner._superbatch, static_argnames="image_shape").lower(
+        _spec(one_chip, (J, n, 32 * 32 * 3)),
+        _spec(one_chip, (160, 1, 256), jnp.int32),
+        image_shape=image).compile().memory_analysis()
+    assert abs(gather.output_size_in_bytes - 2_516_582_400) \
+        < 0.05 * 2_516_582_400
+    assert gather.temp_size_in_bytes < 1e9

@@ -133,8 +133,11 @@ def test_runner_and_prefetcher_spans_in_a_trace(tmp_path):
     for line, name, stats in events:
         by_name.setdefault(name, []).append((line, stats))
     rounds = n // b
-    item_bytes = (views[:, :rounds * b].nbytes + 4 * rounds * b
+    # the item: the (K, 1, b) int32 index matrix, the labels it picks and
+    # the round keys; the superbatch is gathered from the resident views
+    item_bytes = (4 * rounds * b + 4 * rounds * b
                   + rounds * jax.random.PRNGKey(0).nbytes)
+    superbatch_bytes = views[:, :rounds * b].nbytes
     assemble = by_name["repro.runner.assemble"]
     put = by_name["repro.prefetch.put"]
     assert len(assemble) == len(put) == 2
@@ -148,3 +151,11 @@ def test_runner_and_prefetcher_spans_in_a_trace(tmp_path):
     waits = {line for line, _ in by_name["repro.prefetch.wait"]}
     assert len(producer) == 1 and producer != main and waits == main
     assert len(by_name["repro.prefetch.wait"]) == 2   # one pull an epoch
+    # the runner's thread gathers each epoch's superbatch on the device
+    # from the view set it made resident once
+    gather = by_name["repro.runner.gather"]
+    assert len(gather) == 2 and {line for line, _ in gather} == main
+    assert all(stats["rounds"] == rounds
+               and stats["bytes"] == superbatch_bytes for _, stats in gather)
+    resident = by_name["repro.runner.resident"]
+    assert len(resident) == 1 and resident[0][1]["bytes"] == views.nbytes
