@@ -55,6 +55,10 @@ class SSMConfig:
     expand: int = 2
     head_dim: int = 64              # mamba2 SSD head dim
     chunk_size: int = 256           # SSD chunk length
+    # train mode: each mLSTM block also returns its recurrence's inputs and
+    # output (q, k, v, gates, h) as its new state, so that a caller can
+    # hold the recurrence of the program it runs against another form
+    record_mlstm: bool = False
 
     def d_inner(self, d_model: int) -> int:
         return self.expand * d_model

@@ -53,7 +53,8 @@ def sample(key, mu, logvar):
 
 def fused_sample_rate(key, mu, logvar, *, link_bits: int = 32,
                       rate_estimator: str = "sample", prior: dict = None,
-                      backend: str = "auto", block_t: int = None):
+                      backend: str = "auto", block_t: int = None,
+                      eps=None):
     """The cut-layer hot path in ONE fused kernel pass: draws eps and
     returns
 
@@ -74,12 +75,14 @@ def fused_sample_rate(key, mu, logvar, *, link_bits: int = 32,
     prior — a {"mu", "logvar"} dict of (d,) shared or (J, d) per-node
     learned-Gaussian-prior params — switches the eq.-(6) rate to Q_psi and
     stays on the fused path (the kernel also emits the prior gradients);
-    there is no fallback to the unfused 3-pass estimator any more."""
+    there is no fallback to the unfused 3-pass estimator any more.
+
+    eps — the standard-normal draw itself, in place of key's (for a caller
+    that puts the draw under a scope of its own)."""
     from repro.kernels import ops
-    if key is None:
-        eps = jnp.zeros(mu.shape, jnp.float32)
-    else:
-        eps = jax.random.normal(key, mu.shape, jnp.float32)
+    if eps is None:
+        eps = jnp.zeros(mu.shape, jnp.float32) if key is None \
+            else jax.random.normal(key, mu.shape, jnp.float32)
     prior = prior or {}
     return ops.cutlayer(mu, logvar, eps, link_bits=link_bits,
                         rate_estimator=rate_estimator,

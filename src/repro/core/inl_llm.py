@@ -93,6 +93,16 @@ def encode(params: INLLLMParams, cfg, tokens, rng, *, train: bool = True,
     """tokens: (B,S).  Views differ by per-node embedding + feature noise.
     Returns (u, mu, logvar, rate): u/mu/logvar (J, B, S, d_b); rate
     (J, B, S) fp32 from the fused cut-layer kernel (None when train=False).
+    See `_encode`."""
+    return _encode(params, cfg, tokens, rng, train=train,
+                   rate_estimator=rate_estimator, backend=backend)[:4]
+
+
+def _encode(params: INLLLMParams, cfg, tokens, rng, *, train: bool = True,
+            rate_estimator: str = "sample", backend: str = "auto"):
+    """`encode`'s (u, mu, logvar, rate), then the encoders' block states:
+    None, or with cfg.ssm.record_mlstm their mLSTM blocks' recurrence
+    records, (J, periods, ...) per block of the period.
 
     The per-node encoders run under vmap, but the cut layer itself —
     sample + link quantizer + rate — is ONE fused kernel launch over all
@@ -109,27 +119,33 @@ def encode(params: INLLLMParams, cfg, tokens, rng, *, train: bool = True,
         h = layers.embed(enc["embed"], tokens)
         # view-specific observation noise (sigma grows with node index via key
         # folding is NOT used here: homogeneous sigma keeps nodes exchangeable)
-        h = h + (0.1 * jax.random.normal(nk, h.shape, jnp.float32)
-                 ).astype(h.dtype)
-        h, _, _ = transformer.stack_apply(enc["stack"], e_cfg, h, positions,
-                                          mode="train")
+        with jax.named_scope("cut"):
+            noise = jax.random.normal(nk, h.shape, jnp.float32)
+        h = h + (0.1 * noise).astype(h.dtype)
+        h, states, _ = transformer.stack_apply(enc["stack"], e_cfg, h,
+                                               positions, mode="train")
         h = layers.rmsnorm(enc["norm"], h, cfg.norm_eps)
-        return bottleneck.head_apply(enc["head"], h)
+        return bottleneck.head_apply(enc["head"], h), states
 
-    mu, logvar = jax.vmap(one)(params.encoders, noise_keys)
+    with jax.named_scope("encoder"):
+        (mu, logvar), states = jax.vmap(one)(params.encoders, noise_keys)
     bits = cfg.inl.link_bits if cfg.inl.link_bits > 8 else 32
     if train:
+        # the eps draw is the cut's; the kernel call stays outside every
+        # scope, which would rename its instructions (`_cutlayer_call`)
+        with jax.named_scope("cut"):
+            eps = jax.random.normal(jax.random.fold_in(rng, 1), mu.shape,
+                                    jnp.float32)
         u, rate = bottleneck.fused_sample_rate(
-            jax.random.fold_in(rng, 1), mu, logvar, link_bits=bits,
-            rate_estimator=rate_estimator, prior=params.priors,
-            backend=backend)
+            None, mu, logvar, link_bits=bits, rate_estimator=rate_estimator,
+            prior=params.priors, backend=backend, eps=eps)
     else:
         # deterministic inference cut: same kernel, no-noise mode
         u, _ = bottleneck.fused_sample_rate(
             None, mu, logvar, link_bits=bits, rate_estimator="none",
             backend=backend)
         rate = None
-    return u, mu, logvar, rate
+    return u, mu, logvar, rate, states
 
 
 def decode(params: INLLLMParams, cfg, u, tokens_shape):
@@ -212,26 +228,37 @@ def _chunked_inl_ce(params: INLLLMParams, cfg, h, u, labels,
 def loss_fn(params: INLLLMParams, cfg, batch, rng, *,
             rate_estimator: str = "sample", backend: str = "auto"):
     tokens, labels = batch["tokens"], batch["labels"]
-    u, mu, logvar, rates = encode(params, cfg, tokens, rng, train=True,
-                                  rate_estimator=rate_estimator,
-                                  backend=backend)
-    h, moe_aux = decode(params, cfg, u, tokens.shape)
-    ce_joint, ce_branch_sum, acc = _chunked_inl_ce(params, cfg, h, u, labels)
-    # rates (J,B,S) come from the fused cut-layer kernel — not recomputed
-    rate_total = jnp.mean(rates.reshape(cfg.inl.num_nodes, -1),
-                          axis=-1).sum()
-    loss = ce_joint + cfg.inl.s * (ce_branch_sum + rate_total)
-    metrics = {"ce_joint": ce_joint,
-               "ce_branch_mean": ce_branch_sum / cfg.inl.num_nodes,
-               "rate_mean": rate_total / cfg.inl.num_nodes,
-               "rate_total": rate_total, "accuracy": acc}
-    if cfg.is_moe:
-        loss = loss + cfg.moe.router_aux_weight * moe_aux["lb_loss"] \
-                    + cfg.moe.router_z_weight * moe_aux["z_loss"]
+    u, mu, logvar, rates, states = _encode(
+        params, cfg, tokens, rng, train=True, rate_estimator=rate_estimator,
+        backend=backend)
+    with jax.named_scope("decoder"):
+        h, moe_aux = decode(params, cfg, u, tokens.shape)
+    with jax.named_scope("loss"):
+        ce_joint, ce_branch_sum, acc = _chunked_inl_ce(params, cfg, h, u,
+                                                       labels)
+        # rates (J,B,S) come from the fused cut-layer kernel — not
+        # recomputed
+        rate_total = jnp.mean(rates.reshape(cfg.inl.num_nodes, -1),
+                              axis=-1).sum()
+        loss = ce_joint + cfg.inl.s * (ce_branch_sum + rate_total)
+        metrics = {"ce_joint": ce_joint,
+                   "ce_branch_mean": ce_branch_sum / cfg.inl.num_nodes,
+                   "rate_mean": rate_total / cfg.inl.num_nodes,
+                   "rate_total": rate_total, "accuracy": acc}
+        if cfg.is_moe:
+            loss = loss + cfg.moe.router_aux_weight * moe_aux["lb_loss"] \
+                        + cfg.moe.router_z_weight * moe_aux["z_loss"]
     metrics["loss"] = loss
     J = cfg.inl.num_nodes
     metrics["bits_per_token"] = jnp.asarray(
         2 * J * cfg.inl.d_bottleneck * cfg.inl.link_bits, jnp.float32)
+    if cfg.ssm.record_mlstm:
+        # the J cut means, and node 0's first mLSTM block's recurrence in
+        # its first period
+        first = states["pattern"][
+            list(transformer.block_pattern(cfg)).index("mlstm")]
+        metrics.update({"record." + k: v[0, 0] for k, v in first.items()})
+        metrics["record.mu"] = mu
     return loss, metrics
 
 

@@ -142,7 +142,8 @@ def make_inl_train_step(cfg, optimizer):
     def inl_step(params, opt_state, batch, rng):
         (loss, metrics), grads = jax.value_and_grad(
             inl_llm.loss_fn, has_aux=True)(params, cfg, batch, rng)
-        new_params, new_opt = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, opt_state, params)
         return new_params, new_opt, metrics
     return inl_step
 

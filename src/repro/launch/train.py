@@ -10,18 +10,24 @@ runs reduced (smoke) configs on the host devices.  Supports three schemes:
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b --smoke \
       --steps 50 --batch 8 --seq 128 [--scheme inl] [--ckpt-dir ckpts]
+
+`main` is `setup`, then `run_group` on each scan group `device_groups`
+yields, then `finish`; other callers (the chip benchmark's driver) run the
+same three with a configuration of their own.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
+from typing import Any, Callable, Iterator, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import checkpoint, compile_cache, optim
+from repro import checkpoint, compile_cache, optim, tracing
 from repro.configs import get_config, get_smoke_config
 from repro.core import inl_llm
 from repro.data import prefetch
@@ -37,7 +43,7 @@ def make_optimizer(lr: float, steps: int):
         weight_decay=0.1, clip_norm=1.0)
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -70,16 +76,15 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10,
                     help="(superseded: metrics are logged once per scan "
                          "group, i.e. every --scan-steps steps)")
-    args = ap.parse_args(argv)
-    compile_cache.enable()
+    return ap.parse_args(argv)
 
+
+def model_config(args):
+    """--arch's configuration as the CLI trains it: the reduced one in
+    float32 with --smoke; for the inl scheme grown to enough periods for
+    the split, with learned priors on --learned-prior."""
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    import dataclasses
     cfg = dataclasses.replace(cfg, dtype="float32") if args.smoke else cfg
-
-    key = jax.random.PRNGKey(args.seed)
-    optimizer = make_optimizer(args.lr, args.steps)
-
     if args.scheme == "inl":
         from repro.models import transformer
         # the INL split needs >= encoder_layers + 1 periods; smoke configs
@@ -92,12 +97,51 @@ def main(argv=None):
         if args.learned_prior:
             cfg = dataclasses.replace(
                 cfg, inl=dataclasses.replace(cfg.inl, learned_prior=True))
-        params = inl_llm.init(cfg, key)
-        opt_state = optimizer.init(params)
+    return cfg
+
+
+@dataclasses.dataclass
+class Trainer:
+    """What the training loop carries from one scan group to the next."""
+    args: argparse.Namespace
+    cfg: Any
+    params: Any
+    opt_state: Any
+    epoch_fn: Callable      # the jitted scan of --scan-steps steps
+    data: Iterator          # the token stream, one batch per step
+    rng: Any                # inl: split once per scan group
+    step: int = 0           # optimizer steps done
+    t0: float = 0.0
+    history: List[dict] = dataclasses.field(default_factory=list)
+
+    @property
+    def group_size(self) -> int:
+        return max(self.args.scan_steps, 1)
+
+
+def group_keys(rng, k: int):
+    """(next rng, the k per-step keys of one inl scan group)."""
+    rng, sub = jax.random.split(rng)
+    return rng, jax.random.split(sub, k)
+
+
+def setup(args, cfg=None) -> Trainer:
+    """Parameters, optimizer state, the jitted scan, the token stream and
+    the rng for `args`; with --resume, restored from the latest checkpoint
+    with the streams fast-forwarded past the completed steps.  `cfg`
+    replaces `model_config(args)`."""
+    cfg = model_config(args) if cfg is None else cfg
+    key = jax.random.PRNGKey(args.seed)
+    optimizer = make_optimizer(args.lr, args.steps)
+
+    if args.scheme == "inl":
+        init = inl_llm.init
     else:
         from repro.models import zoo
-        params = zoo.init_params(cfg, key)
-        opt_state = optimizer.init(params)
+        init = zoo.init_params
+    # one program each (op by op, a large model's init compiles hundreds)
+    params = jax.jit(init, static_argnums=0)(cfg, key)
+    opt_state = jax.jit(optimizer.init)(params)
     epoch_fn = steps_lib.make_scan_train_step(
         cfg, optimizer, scheme=args.scheme, microbatches=args.microbatches)
 
@@ -107,79 +151,108 @@ def main(argv=None):
 
     data = token_data.lm_batches(cfg, args.batch, args.seq, steps=args.steps,
                                  seed=args.seed)
-    rng = jax.random.PRNGKey(args.seed + 1)
-    K = max(args.scan_steps, 1)
-    step = 0
+    tr = Trainer(args, cfg, params, opt_state, epoch_fn, data,
+                 jax.random.PRNGKey(args.seed + 1))
     if args.resume and args.ckpt_dir:
         latest = checkpoint.latest_step(args.ckpt_dir)
         if latest is not None:
             restored, _ = checkpoint.restore(
                 args.ckpt_dir, {"params": params, "opt": opt_state},
                 step=latest)
-            params, opt_state = restored["params"], restored["opt"]
-            step = latest
+            tr.params, tr.opt_state = restored["params"], restored["opt"]
+            tr.step = latest
             # fast-forward the streams through the completed work: the data
             # generator is deterministic per (cfg, seed), and the inl rng
             # splits once per scan group — replaying both makes the resumed
             # subkeys (and so the trajectory) the uninterrupted run's
-            for _ in range(step):
+            for _ in range(latest):
                 next(data)
             if args.scheme == "inl":
-                for _ in range((step + K - 1) // K):
-                    rng, _ = jax.random.split(rng)
-            print(f"resumed from step {step} ({args.ckpt_dir})")
-    t0 = time.time()
-    history = []
+                for _ in range((latest + tr.group_size - 1)
+                               // tr.group_size):
+                    tr.rng, _ = jax.random.split(tr.rng)
+            print(f"resumed from step {latest} ({args.ckpt_dir})")
+    tr.t0 = time.time()
+    return tr
 
-    def run_group(params, opt_state, rng, batches, k):
-        # one jitted scan over the group: K optimizer steps, zero
-        # per-step dispatch, donated params/opt_state; `batches` arrives
-        # stacked AND device-resident from the prefetcher
-        nonlocal step
+
+def stacked_groups(tr: Trainer) -> Iterator:
+    """The token stream in scan groups stacked (K, ...) on the host; run on
+    the prefetcher's producer thread."""
+    for group in steps_lib.grouped_batches(tr.data, tr.group_size):
+        with tracing.span("train.stack") as sp:
+            item = steps_lib.stack_batches(group)
+            sp.set_metadata(bytes=prefetch.nbytes(item))
+        yield item
+
+
+def device_groups(tr: Trainer) -> Iterator:
+    """The scan groups, device-resident: the scan crosses the data-loading
+    boundary, as groups are stacked host-side and device_put by the
+    double-buffered prefetcher, so the transfer of group g+1 overlaps the
+    scan executing group g."""
+    return prefetch.prefetch_to_device(stacked_groups(tr),
+                                       size=max(tr.args.prefetch, 1))
+
+
+def run_group(tr: Trainer, batches):
+    """One jitted scan over the group: K optimizer steps, zero per-step
+    dispatch, donated params/opt_state; `batches` arrives stacked AND
+    device-resident from the prefetcher.  Logs the group's last step and
+    checkpoints when the group crossed a --ckpt-every boundary; returns the
+    stacked per-step metrics."""
+    args = tr.args
+    k = jax.tree.leaves(batches)[0].shape[0]
+    with tracing.span("train.group", steps=k,
+                      tokens=int(np.prod(batches["labels"].shape))):
         if args.scheme == "inl":
-            rng, sub = jax.random.split(rng)
-            rngs = jax.random.split(sub, k)
-            params, opt_state, ms = epoch_fn(params, opt_state, batches,
-                                             rngs)
+            tr.rng, rngs = group_keys(tr.rng, k)
+            tr.params, tr.opt_state, ms = tr.epoch_fn(
+                tr.params, tr.opt_state, batches, rngs)
         else:
-            params, opt_state, ms = epoch_fn(params, opt_state, batches)
-        prev_step, step = step, step + k
-        last = jax.tree.map(lambda x: x[-1], ms)
-        m = {k: float(v) for k, v in last.items() if jnp.ndim(v) == 0}
-        m["step"] = step - 1
-        m["wall_s"] = round(time.time() - t0, 1)
-        history.append(m)
-        print(json.dumps({k: (round(v, 4) if isinstance(v, float) else v)
-                          for k, v in m.items()}), flush=True)
-        # checkpoint when the group crossed a --ckpt-every boundary (step
-        # advances by the group size, so an exact-multiple test would skip)
-        if args.ckpt_dir and args.ckpt_every and \
-                step // args.ckpt_every > prev_step // args.ckpt_every:
-            checkpoint.save(args.ckpt_dir, step,
-                            {"params": params, "opt": opt_state},
-                            extra={"arch": cfg.name, "scheme": args.scheme})
-        return params, opt_state, rng
+            tr.params, tr.opt_state, ms = tr.epoch_fn(
+                tr.params, tr.opt_state, batches)
+        # the last step's scalar metrics; a per-step array (a recurrence
+        # record) stays on the device, unsliced
+        m = {k: float(v[-1]) for k, v in ms.items() if jnp.ndim(v) == 1}
+    prev_step, tr.step = tr.step, tr.step + k
+    m["step"] = tr.step - 1
+    m["wall_s"] = round(time.time() - tr.t0, 1)
+    tr.history.append(m)
+    print(json.dumps({k: (round(v, 4) if isinstance(v, float) else v)
+                      for k, v in m.items()}), flush=True)
+    # checkpoint when the group crossed a --ckpt-every boundary (step
+    # advances by the group size, so an exact-multiple test would skip)
+    if args.ckpt_dir and args.ckpt_every and \
+            tr.step // args.ckpt_every > prev_step // args.ckpt_every:
+        checkpoint.save(args.ckpt_dir, tr.step,
+                        {"params": tr.params, "opt": tr.opt_state},
+                        extra={"arch": tr.cfg.name, "scheme": args.scheme})
+    return ms
 
-    # the scan now crosses the data-loading boundary: groups are stacked
-    # host-side and device_put by the double-buffered prefetcher, so the
-    # transfer of group g+1 overlaps the scan executing group g
-    stacked = (steps_lib.stack_batches(g)
-               for g in steps_lib.grouped_batches(data, K))
-    for batches in prefetch.prefetch_to_device(stacked,
-                                               size=max(args.prefetch, 1)):
-        k = jax.tree.leaves(batches)[0].shape[0]
-        params, opt_state, rng = run_group(params, opt_state, rng, batches,
-                                           k)
+
+def finish(tr: Trainer) -> List[dict]:
+    """The final checkpoint and the loss summary; returns the history."""
+    args = tr.args
     if args.ckpt_dir:
         checkpoint.save(args.ckpt_dir, args.steps,
-                        {"params": params, "opt": opt_state},
-                        extra={"arch": cfg.name, "scheme": args.scheme})
-    if history:
-        first, last = history[0], history[-1]
+                        {"params": tr.params, "opt": tr.opt_state},
+                        extra={"arch": tr.cfg.name, "scheme": args.scheme})
+    if tr.history:
+        first, last = tr.history[0], tr.history[-1]
         key_metric = "loss" if "loss" in last else "ce"
         print(f"loss {first[key_metric]:.4f} -> {last[key_metric]:.4f} "
-              f"({args.steps} steps, {time.time()-t0:.1f}s)")
-    return history
+              f"({args.steps} steps, {time.time()-tr.t0:.1f}s)")
+    return tr.history
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    compile_cache.enable()
+    tr = setup(args)
+    for batches in device_groups(tr):
+        run_group(tr, batches)
+    return finish(tr)
 
 
 if __name__ == "__main__":

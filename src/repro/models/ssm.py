@@ -283,7 +283,11 @@ def mlstm_make_state(cfg, batch: int, dtype):
 
 
 def _mlstm_cell(carry, qkvif):
-    """One step of the stabilised mLSTM recurrence.  All fp32."""
+    """One step of the stabilised mLSTM recurrence.  All fp32.
+
+    C and n are held scaled by exp(-m), so the paper's normaliser
+    max(|n_t . q_t|, 1) on the unscaled state reads max(|n . q|, exp(-m))
+    on the scaled one (arXiv:2405.04517, the stabilised mLSTM)."""
     C, n, m = carry
     q, k, v, i_raw, f_raw = qkvif                        # (B,H,dh) x3, (B,H) x2
     log_f = -jax.nn.softplus(-f_raw)                     # log sigmoid(f)
@@ -294,9 +298,26 @@ def _mlstm_cell(carry, qkvif):
         k[..., :, None] * v[..., None, :])               # (B,H,dh_k,dh_v)
     n_new = f_g[..., None] * n + i_g[..., None] * k
     num = jnp.einsum("bhkv,bhk->bhv", C_new, q)
-    den = jnp.maximum(jnp.abs(jnp.einsum("bhk,bhk->bh", n_new, q)), 1.0)
+    den = jnp.maximum(jnp.abs(jnp.einsum("bhk,bhk->bh", n_new, q)),
+                      jnp.exp(-m_new))
     h = num / den[..., None]
     return (C_new, n_new, m_new), h
+
+
+def mlstm_scan(q, k, v, i_raw, f_raw, chunk: int):
+    """The mLSTM recurrence over a sequence from the zero state, remat'd
+    every `chunk` steps.  q, k, v: (B, S, H, dh); i_raw, f_raw: (B, S, H);
+    all fp32.  Returns (h (B, S, H, dh), the final (C, n, m))."""
+    Bsz, S, H, dh = q.shape
+    init = (jnp.zeros((Bsz, H, dh, dh), jnp.float32),
+            jnp.zeros((Bsz, H, dh), jnp.float32),
+            jnp.full((Bsz, H), -1e30, jnp.float32))
+    seq = (jnp.moveaxis(q, 1, 0), jnp.moveaxis(k, 1, 0),
+           jnp.moveaxis(v, 1, 0), jnp.moveaxis(i_raw, 1, 0),
+           jnp.moveaxis(f_raw, 1, 0))
+    with jax.named_scope("mlstm"):
+        carry, hs = _scan_chunked_remat(_mlstm_cell, init, seq, S, chunk)
+    return jnp.moveaxis(hs, 0, 1), carry
 
 
 def mlstm_apply(p, cfg, x, *, mode: str, state=None):
@@ -327,24 +348,18 @@ def mlstm_apply(p, cfg, x, *, mode: str, state=None):
 
     if mode == "decode":
         carry = (state["C"], state["n"], state["m"])
-        carry, h = _mlstm_cell(carry, (q[:, 0], k[:, 0], v[:, 0],
-                                       i_raw[:, 0], f_raw[:, 0]))
+        with jax.named_scope("mlstm"):
+            carry, h = _mlstm_cell(carry, (q[:, 0], k[:, 0], v[:, 0],
+                                           i_raw[:, 0], f_raw[:, 0]))
         h = h[:, None]                                    # (B,1,H,dh)
         new_state = {"C": carry[0], "n": carry[1], "m": carry[2],
                      "conv": conv_state}
     else:
-        def scan_step(carry, t):
-            return _mlstm_cell(carry, t)
-        init = (jnp.zeros((Bsz, H, dh, dh), jnp.float32),
-                jnp.zeros((Bsz, H, dh), jnp.float32),
-                jnp.full((Bsz, H), -1e30, jnp.float32))
-        seq = (jnp.moveaxis(q, 1, 0), jnp.moveaxis(k, 1, 0),
-               jnp.moveaxis(v, 1, 0), jnp.moveaxis(i_raw, 1, 0),
-               jnp.moveaxis(f_raw, 1, 0))
-        carry, hs = _scan_chunked_remat(scan_step, init, seq, q.shape[1],
-                                        cfg.ssm.chunk_size)
-        h = jnp.moveaxis(hs, 0, 1)                        # (B,S,H,dh)
+        h, carry = mlstm_scan(q, k, v, i_raw, f_raw, cfg.ssm.chunk_size)
         new_state = None
+        if mode == "train" and cfg.ssm.record_mlstm:
+            new_state = {"q": q, "k": k, "v": v, "i": i_raw, "f": f_raw,
+                         "h": h}
         if mode == "prefill":
             raw_tail = jnp.pad(xm, ((0, 0), (max(0, cfg.ssm.conv_width - 1 - S),
                                              0), (0, 0)))
@@ -422,7 +437,8 @@ def slstm_apply(p, cfg, x, *, mode: str, state=None):
     if mode == "decode":
         assert S == 1 and state is not None
         carry = (state["c"], state["n"], state["h"], state["m"])
-        carry = _slstm_cell(p["r"], carry, xg[:, 0], H, dh)
+        with jax.named_scope("slstm"):
+            carry = _slstm_cell(p["r"], carry, xg[:, 0], H, dh)
         hs = carry[2][:, None]                           # (B,1,H,dh)
         new_state = {"c": carry[0], "n": carry[1], "h": carry[2],
                      "m": carry[3]}
@@ -434,8 +450,10 @@ def slstm_apply(p, cfg, x, *, mode: str, state=None):
                 jnp.zeros((Bsz, H, dh), jnp.float32),
                 jnp.zeros((Bsz, H, dh), jnp.float32),
                 jnp.full((Bsz, H, dh), -1e30, jnp.float32))
-        carry, hs = _scan_chunked_remat(step, init, jnp.moveaxis(xg, 1, 0),
-                                        S, cfg.ssm.chunk_size)
+        with jax.named_scope("slstm"):
+            carry, hs = _scan_chunked_remat(step, init,
+                                            jnp.moveaxis(xg, 1, 0), S,
+                                            cfg.ssm.chunk_size)
         hs = jnp.moveaxis(hs, 0, 1)                      # (B,S,H,dh)
         new_state = None
         if mode == "prefill":

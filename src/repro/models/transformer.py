@@ -288,7 +288,10 @@ def stack_apply(p, cfg, x, positions, *, mode, cache=None, cache_len=None):
     shared = p.get("shared")
     emb0 = x if shared is not None else None
     aux_sum = _zero_aux(cfg)
-    new_cache = {"pattern": []} if mode != "train" else None
+    # train mode keeps the blocks' new states only as cfg.ssm.record_mlstm
+    # asks (each mLSTM block's recurrence record)
+    keep = mode != "train" or cfg.ssm.record_mlstm
+    new_cache = {"pattern": []} if keep else None
 
     if "pre" in p:
         if mode != "train":
@@ -318,7 +321,7 @@ def stack_apply(p, cfg, x, positions, *, mode, cache=None, cache_len=None):
             xx = _checkpoint_name(xx, "block_out")
             aux_acc = jax.tree.map(jnp.add, aux_acc, aux)
             caches_out.append(nc)
-        out = {"cache": caches_out} if mode != "train" else {"cache": None}
+        out = {"cache": caches_out if keep else None}
         return (xx, aux_acc), out
 
     scanned_in = {"params": p["pattern"]}
@@ -336,7 +339,7 @@ def stack_apply(p, cfg, x, positions, *, mode, cache=None, cache_len=None):
                 period_body,
                 policy=jax.checkpoint_policies.nothing_saveable)
         (x, aux_sum), outs = jax.lax.scan(body, (x, aux_sum), scanned_in)
-        if mode != "train":
+        if keep:
             new_cache["pattern"] = outs["cache"]
     else:
         carry = (x, aux_sum)
@@ -346,7 +349,7 @@ def stack_apply(p, cfg, x, positions, *, mode, cache=None, cache_len=None):
             carry, out = period_body(carry, sl)
             outs.append(out)
         x, aux_sum = carry
-        if mode != "train":
+        if keep:
             new_cache["pattern"] = jax.tree.map(
                 lambda *ts: jnp.stack(ts), *[o["cache"] for o in outs])
     return x, new_cache, aux_sum

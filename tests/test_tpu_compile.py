@@ -185,3 +185,45 @@ def test_runner_device_gather_fits_the_chip(one_chip):
     assert abs(gather.output_size_in_bytes - 2_516_582_400) \
         < 0.05 * 2_516_582_400
     assert gather.temp_size_in_bytes < 1e9
+
+
+def test_xlstm_inl_step_keeps_the_cut_kernels_instruction_names(
+        one_chip, monkeypatch):
+    """The xLSTM INL split's train step (core/inl_llm.py, at a tiny size)
+    with its named scopes: the cut layer's two kernels keep the names the
+    cut-layer reader (bench/metrics/cutlayer_us_per_step.xlstm_train.py)
+    finds, and the scopes reach the compiled program's op_names."""
+    import dataclasses
+    import re
+
+    from repro import optim
+    from repro.configs import get_smoke_config
+    from repro.core import inl_llm
+    from repro.kernels import ops
+    from repro.launch import steps
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = get_smoke_config("xlstm-125m")
+    cfg = dataclasses.replace(cfg, num_layers=4)
+    opt = optim.adamw(1e-3)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
+                            tree)
+    params = on_chip(jax.eval_shape(lambda k: inl_llm.init(cfg, k),
+                                    jax.random.PRNGKey(0)))
+    opt_state = on_chip(jax.eval_shape(opt.init, params))
+    batch = {"tokens": _spec(one_chip, (1, 128), jnp.int32),
+             "labels": _spec(one_chip, (1, 128), jnp.int32)}
+    rng = _spec(one_chip, (2,), jnp.uint32)
+    text = jax.jit(steps.make_inl_train_step(cfg, opt)).lower(
+        params, opt_state, batch, rng).compile().as_text()
+    kernels = sorted(
+        re.sub(r"\.\d+$", "", line.split(" = ")[0].strip())
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+    assert kernels == ["%jvp_jit__cutlayer_call__",
+                       "%transpose_jvp_jit__cutlayer_call___"]
+    for scope in ("encoder", "cut", "decoder", "loss", "optimizer", "mlstm",
+                  "slstm"):
+        assert re.search(rf'op_name="[^"]*/(?:\w+\()*{scope}\)*/', text), \
+            scope
