@@ -54,7 +54,7 @@ class SSMConfig:
     conv_width: int = 4
     expand: int = 2
     head_dim: int = 64              # mamba2 SSD head dim
-    chunk_size: int = 256           # SSD chunk length
+    chunk_size: int = 256           # SSD / mLSTM chunk length; sLSTM remat
     # train mode: each mLSTM block also returns its recurrence's inputs and
     # output (q, k, v, gates, h) as its new state, so that a caller can
     # hold the recurrence of the program it runs against another form

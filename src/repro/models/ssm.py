@@ -5,8 +5,11 @@ Mamba2 uses the chunked SSD formulation: intra-chunk terms are dense einsums
 a tiny lax.scan only for the inter-chunk state recurrence.  The Pallas kernel
 (repro.kernels.ssm_scan) implements the same chunked contract for TPU.
 
-xLSTM blocks use exact sequential recurrences (lax.scan over time) with
-exponential gating + max-stabiliser state, faithful to arXiv:2405.04517.
+xLSTM blocks keep exponential gating + max-stabiliser state, faithful to
+arXiv:2405.04517.  Over a sequence the mLSTM runs chunkwise-parallel
+(dense matmuls within a chunk, a lax.scan only over the chunk states); at
+decode it steps its recurrent cell.  The sLSTM's recurrent gates leave it
+a sequential lax.scan over time.
 """
 from __future__ import annotations
 
@@ -211,11 +214,10 @@ def _scan_chunked_remat(cell, init, seq, S: int, chunk: int):
     """Time scan with chunk-level rematerialisation.
 
     A plain lax.scan over S steps saves every per-step carry for the
-    backward pass — for mLSTM the carry holds the (B,H,dh,dh) matrix memory,
-    i.e. 4096 x 600 MB at 4k context (measured 179 GB/device on xlstm-125m
-    train_4k).  Scanning checkpointed CHUNKS saves carries only at chunk
-    boundaries and recomputes inside: S/chunk boundary saves + one in-chunk
-    recompute, ~chunk x less carry residency.
+    backward pass (the sLSTM's c, n, h, m per step).  Scanning
+    checkpointed CHUNKS saves carries only at chunk boundaries and
+    recomputes inside: S/chunk boundary saves + one in-chunk recompute,
+    ~chunk x less carry residency.
 
     cell: (carry, step_inputs) -> (carry, y); seq: tuple of time-major
     (S, ...) arrays.  Falls back to the plain scan when chunk doesn't
@@ -304,20 +306,82 @@ def _mlstm_cell(carry, qkvif):
     return (C_new, n_new, m_new), h
 
 
+def _mlstm_chunk(carry, chunk):
+    """One chunk of the mLSTM recurrence from the state entering it,
+    chunkwise-parallel: the chunk's h and the state leaving it.
+
+    carry: (C (B,H,dh,dh), n (B,H,dh), m (B,H)) in `_mlstm_cell`'s
+    stabilised form; chunk: q, k, v (B,H,L,dh), i_raw, f_raw (B,H,L).
+    With b_t the cumulative log forget gate from the chunk's start, m_t is
+    the recurrent stabiliser max(b_t + m, max_{s<=t} b_t - b_s + i_s), and
+    h_t = (w_t q_t C + sum_s D_ts (q_t . k_s) v_s)
+          / max(|w_t q_t . n + sum_s D_ts (q_t . k_s)|, exp(-m_t))
+    with w_t = exp(b_t + m - m_t), D_ts = exp(b_t - b_s + i_s - m_t).  Every
+    product of the float32 state runs at HIGHEST.  h is invariant to m_t,
+    so m_t takes no gradient.  The chunk's first L-1 tokens enter the state
+    as one product; the last enters through `_mlstm_cell`, so the state
+    carried from chunk to chunk is the recurrent cell's own."""
+    C, n, m = carry
+    q, k, v, i_raw, f_raw = chunk
+    L = q.shape[-2]
+    hi = jax.lax.Precision.HIGHEST
+    b = jnp.cumsum(-jax.nn.softplus(-f_raw), axis=-1)     # (B,H,L)
+    log_d = b[..., :, None] - b[..., None, :] + i_raw[..., None, :]
+    log_d = jnp.where(jnp.tril(jnp.ones((L, L), bool)), log_d, -jnp.inf)
+    m_t = jax.lax.stop_gradient(
+        jnp.maximum(b + m[..., None], jnp.max(log_d, axis=-1)))
+    w = jnp.exp(b + m[..., None] - m_t)                    # (B,H,L)
+    d = jnp.exp(log_d - m_t[..., None])                    # (B,H,L,L)
+    s = jnp.einsum("bhtd,bhsd->bhts", q, k, precision=hi) * d
+    num = w[..., None] * jnp.einsum("bhtd,bhde->bhte", q, C, precision=hi) \
+        + jnp.einsum("bhts,bhse->bhte", s, v, precision=hi)
+    den = jnp.maximum(
+        jnp.abs(w * jnp.einsum("bhtd,bhd->bht", q, n, precision=hi)
+                + jnp.sum(s, axis=-1)), jnp.exp(-m_t))
+    h = num / den[..., None]
+    if L > 1:
+        # the state after the first L-1 tokens: row L-2 of w and D
+        dk = d[..., L - 2, :, None] * k                    # (B,H,L,dh)
+        carry = (w[..., L - 2, None, None] * C
+                 + jnp.einsum("bhsd,bhse->bhde", dk, v, precision=hi),
+                 w[..., L - 2, None] * n + jnp.sum(dk, axis=-2),
+                 m_t[..., L - 2])
+    last = (q[..., -1, :], k[..., -1, :], v[..., -1, :], i_raw[..., -1],
+            f_raw[..., -1])
+    carry, _ = _mlstm_cell(carry, last)
+    return carry, h
+
+
 def mlstm_scan(q, k, v, i_raw, f_raw, chunk: int):
-    """The mLSTM recurrence over a sequence from the zero state, remat'd
-    every `chunk` steps.  q, k, v: (B, S, H, dh); i_raw, f_raw: (B, S, H);
-    all fp32.  Returns (h (B, S, H, dh), the final (C, n, m))."""
+    """The mLSTM recurrence over a sequence from the zero state, in chunks
+    of `chunk` tokens (`_mlstm_chunk`), with a `lax.scan` over the chunk
+    states only.  q, k, v: (B, S, H, dh); i_raw, f_raw: (B, S, H); all
+    fp32.  A ragged tail is padded with i = -inf and log f = 0, which leave
+    the state as it was, and its h dropped.  Returns (h (B, S, H, dh), the
+    final (C, n, m))."""
     Bsz, S, H, dh = q.shape
-    init = (jnp.zeros((Bsz, H, dh, dh), jnp.float32),
-            jnp.zeros((Bsz, H, dh), jnp.float32),
-            jnp.full((Bsz, H), -1e30, jnp.float32))
-    seq = (jnp.moveaxis(q, 1, 0), jnp.moveaxis(k, 1, 0),
-           jnp.moveaxis(v, 1, 0), jnp.moveaxis(i_raw, 1, 0),
-           jnp.moveaxis(f_raw, 1, 0))
+    L = min(chunk, S)
+    nc = -(-S // L)
+    pad = nc * L - S
     with jax.named_scope("mlstm"):
-        carry, hs = _scan_chunked_remat(_mlstm_cell, init, seq, S, chunk)
-    return jnp.moveaxis(hs, 0, 1), carry
+        init = (jnp.zeros((Bsz, H, dh, dh), jnp.float32),
+                jnp.zeros((Bsz, H, dh), jnp.float32),
+                jnp.full((Bsz, H), -1e30, jnp.float32))
+
+        def chunks(t, fill=0.0):
+            t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2),
+                        constant_values=fill)
+            t = t.reshape((Bsz, nc, L) + t.shape[2:])
+            return jnp.moveaxis(t, (1, 2), (0, 3))        # (nc, B, H, L, ...)
+        seq = (chunks(q), chunks(k), chunks(v), chunks(i_raw, -jnp.inf),
+               chunks(f_raw, jnp.inf))
+        # checkpointed, the backward pass keeps one entering state per chunk
+        # and recomputes the chunk; a closure of this call, so that the scan
+        # traces the chunk anew with the `_mlstm_cell` the module holds now
+        body = jax.checkpoint(lambda c, x: _mlstm_chunk(c, x))
+        carry, hs = jax.lax.scan(body, init, seq)
+        hs = jnp.moveaxis(hs, (0, 3), (1, 2)).reshape(Bsz, nc * L, H, dh)
+    return hs[:, :S], carry
 
 
 def mlstm_apply(p, cfg, x, *, mode: str, state=None):

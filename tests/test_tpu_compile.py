@@ -187,21 +187,18 @@ def test_runner_device_gather_fits_the_chip(one_chip):
     assert gather.temp_size_in_bytes < 1e9
 
 
-def test_xlstm_inl_step_keeps_the_cut_kernels_instruction_names(
-        one_chip, monkeypatch):
-    """The xLSTM INL split's train step (core/inl_llm.py, at a tiny size)
-    with its named scopes: the cut layer's two kernels keep the names the
-    cut-layer reader (bench/metrics/cutlayer_us_per_step.xlstm_train.py)
-    finds, and the scopes reach the compiled program's op_names."""
+@pytest.fixture(scope="module")
+def xlstm_inl_step_hlo(one_chip):
+    """The compiled text of the xLSTM INL split's train step
+    (core/inl_llm.py, at a tiny size: 128 tokens, two 64-token mLSTM
+    chunks) for the described v5e, with the Pallas cut layer."""
     import dataclasses
-    import re
 
     from repro import optim
     from repro.configs import get_smoke_config
     from repro.core import inl_llm
     from repro.kernels import ops
     from repro.launch import steps
-    monkeypatch.setattr(ops, "on_tpu", lambda: True)
     cfg = get_smoke_config("xlstm-125m")
     cfg = dataclasses.replace(cfg, num_layers=4)
     opt = optim.adamw(1e-3)
@@ -215,8 +212,20 @@ def test_xlstm_inl_step_keeps_the_cut_kernels_instruction_names(
     batch = {"tokens": _spec(one_chip, (1, 128), jnp.int32),
              "labels": _spec(one_chip, (1, 128), jnp.int32)}
     rng = _spec(one_chip, (2,), jnp.uint32)
-    text = jax.jit(steps.make_inl_train_step(cfg, opt)).lower(
-        params, opt_state, batch, rng).compile().as_text()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "on_tpu", lambda: True)
+        return jax.jit(steps.make_inl_train_step(cfg, opt)).lower(
+            params, opt_state, batch, rng).compile().as_text()
+
+
+def test_xlstm_inl_step_keeps_the_cut_kernels_instruction_names(
+        xlstm_inl_step_hlo):
+    """The xLSTM INL split's train step with its named scopes: the cut
+    layer's two kernels keep the names the cut-layer reader
+    (bench/metrics/cutlayer_us_per_step.xlstm_train.py) finds, and the
+    scopes reach the compiled program's op_names."""
+    import re
+    text = xlstm_inl_step_hlo
     kernels = sorted(
         re.sub(r"\.\d+$", "", line.split(" = ")[0].strip())
         for line in text.splitlines()
@@ -227,3 +236,30 @@ def test_xlstm_inl_step_keeps_the_cut_kernels_instruction_names(
                   "slstm"):
         assert re.search(rf'op_name="[^"]*/(?:\w+\()*{scope}\)*/', text), \
             scope
+
+
+def test_the_recurrence_reader_sees_the_chunkwise_mlstms_matmuls(
+        xlstm_inl_step_hlo):
+    """The instructions `recurrence_us_per_step.xlstm_train` times
+    (bench/drivers/llm_train.recurrence_program) hold the chunkwise
+    mLSTM's matrix products, forward and backward, and not only the
+    sLSTM's."""
+    import re
+
+    from bench import program_trace
+    from bench.drivers import llm_train
+    text = xlstm_inl_step_hlo
+    recurrent = llm_train.recurrence_program(text)["recurrent"]
+    _, names = program_trace.op_names(text)
+    opcode = {}
+    for line in text.splitlines():
+        hit = re.match(r"^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=\s*\S+\s+"
+                       r"([a-z][\w-]*)\(", line)
+        if hit:
+            opcode[hit.group(1)] = hit.group(2)
+    products = [names[i] for i in recurrent
+                if opcode.get(i) in ("dot", "convolution")]
+    mlstm = [n for n in products if re.search(r"/(?:\w+\()*mlstm\)*/", n)]
+    assert recurrent and mlstm, sorted(opcode.get(i) for i in recurrent)
+    assert any("transpose(" in n for n in mlstm)
+    assert any("transpose(" not in n for n in mlstm)
