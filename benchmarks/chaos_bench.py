@@ -270,13 +270,13 @@ def training_churn_section(*, smoke: bool, epochs: int, seed: int):
     rng1 = jax.random.PRNGKey(seed + 9)
     inl_scheme, sl_scheme = schemes.get("inl"), schemes.get("sl")
     st_inl = inl_scheme.init(cfg, jax.random.PRNGKey(seed))
-    new_inl, _ = inl_scheme.make_transport_round(cfg)(
+    new_inl, _ = inl_scheme.make_round(cfg)(
         st_inl, v1, l1, rng1, delivery)
     inl_moved = any(not np.array_equal(a, b) for a, b in
                     zip(jax.tree.leaves(jax.device_get(new_inl)),
                         jax.tree.leaves(jax.device_get(st_inl))))
     st_sl = sl_scheme.init(cfg, jax.random.PRNGKey(seed))
-    new_sl, _ = sl_scheme.make_transport_round(cfg)(
+    new_sl, _ = sl_scheme.make_round(cfg)(
         st_sl, v1, l1, rng1, delivery)
     sl_held = all(np.array_equal(a, b) for a, b in
                   zip(jax.tree.leaves(jax.device_get(new_sl)),

@@ -16,8 +16,7 @@ provided, since the paper allows per-node architectures to differ.
 """
 from __future__ import annotations
 
-from functools import partial
-from typing import Callable, NamedTuple, Optional
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -157,12 +156,12 @@ def loss_fn(params: INLParams, state, views, labels, rng, cfg, *,
     heads and rate terms stay local and unmasked: a cut-off node keeps
     training its own head.
 
-    delivery — an EXPLICIT (J,) or (J, B) delivery mask that overrides the
-    in-graph fault draw entirely: the transport layer
-    (repro/transport/NetworkTransport) measures which views actually
-    arrived this round — after retries, circuit breakers and chaos — and
-    feeds the outcome in as data.  None keeps the legacy in-graph draws
-    (or the perfect network) bit for bit."""
+    delivery — a (J,) or (J, B) delivery mask given as data, which takes
+    the place of the in-graph draw: the runner passes the transport's
+    verdict for the round (repro/transport/NetworkTransport, after
+    retries, circuit breakers and chaos).  None draws the mask in-graph
+    when the links are unreliable, and masks nothing on a perfect
+    network."""
     topo_full = topology_lib.resolve(topology, cfg)
     faulty = delivery is None and linkfault.active(topo_full, cfg,
                                                    train=train)
@@ -223,33 +222,19 @@ def loss_fn(params: INLParams, state, views, labels, rng, cfg, *,
 
 
 def make_train_step(cfg, optimizer, *, rate_estimator: str = "sample",
-                    wire: str = "dense", topology=None,
-                    explicit_delivery: bool = False):
+                    wire: str = "dense", topology=None):
     """jit-able train step closed over the experiment config + optimizer.
 
-    explicit_delivery=True returns the TRANSPORT-mode step: it takes a
-    trailing (J,) / (J, B) delivery-mask argument (the measured transport
-    outcome) instead of drawing faults in-graph."""
-    if explicit_delivery:
-        @jax.jit
-        def step_d(params, state, opt_state, views, labels, rng, delivery):
-            (loss, (metrics, new_state)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(
-                    params, state, views, labels, rng, cfg, train=True,
-                    rate_estimator=rate_estimator, wire=wire,
-                    topology=topology, delivery=delivery)
-            with jax.named_scope("optimizer"):
-                new_params, new_opt = optimizer.update(grads, opt_state,
-                                                       params)
-            return new_params, new_state, new_opt, metrics
-        return step_d
-
+    step(..., delivery) takes the round's (J,) / (J, B) delivery mask as
+    data (see loss_fn); delivery=None draws it in-graph or, on a perfect
+    network, masks nothing."""
     @jax.jit
-    def step(params, state, opt_state, views, labels, rng):
+    def step(params, state, opt_state, views, labels, rng, delivery=None):
         (loss, (metrics, new_state)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, state, views, labels, rng, cfg,
                                    train=True, rate_estimator=rate_estimator,
-                                   wire=wire, topology=topology)
+                                   wire=wire, topology=topology,
+                                   delivery=delivery)
         with jax.named_scope("optimizer"):
             new_params, new_opt = optimizer.update(grads, opt_state, params)
         return new_params, new_state, new_opt, metrics

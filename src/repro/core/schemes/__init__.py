@@ -7,8 +7,10 @@ run on the same measured substrate, so this package makes the harness
 first-class: every scheme sits behind one `Scheme` interface
 
     init(cfg, key, *, lr)        -> opaque state pytree (params + opt state)
-    make_round(cfg, *, lr)       -> jitted round_fn(state, views, labels,
-                                    rng) -> (state, metrics)
+    make_round(cfg, *, lr)       -> round_fn(state, views, labels, rng,
+                                    delivery=None) -> (state, metrics);
+                                    delivery is the round's (J,) fault
+                                    outcome, None to draw it in-graph
     predict(state, views)        -> class probabilities (B, C), rows sum to 1
     bits_per_round(cfg, state, batch_size)
                                  -> bits moved by ONE round, via the

@@ -33,7 +33,9 @@ import jax
 
 
 class Scheme:
-    """Base class: override the five methods; keep `state` a pure pytree."""
+    """Base class: override `init`, `make_round`, `predict`,
+    `bits_per_round` and `wire_bytes_per_round` (the rest have defaults);
+    keep `state` a pure pytree."""
 
     name: str = ""
 
@@ -49,9 +51,21 @@ class Scheme:
 
     def make_round(self, cfg, *, lr: float = 2e-3, wire: str = "dense",
                    topology=None):
-        """Return a jitted round_fn(state, views, labels, rng) ->
+        """Return round_fn(state, views, labels, rng, delivery=None) ->
         (new_state, metrics) with views (R, J, B, H, W, C), labels (R, B),
         R == batches_per_round(cfg).  metrics must include "loss".
+
+        delivery — the round's fault outcome as a (J,) boolean mask, given
+        as data: the runner passes the transport's measured verdict
+        (repro/transport.NetworkTransport.round_outcome — retries, circuit
+        breakers and chaos already applied).  None draws the mask in-graph
+        from `rng` when the topology's links are unreliable
+        (core/linkfault.py), and is a clean round otherwise.  Each scheme
+        applies its own degradation to the same mask: INL partial-fuses
+        the surviving views (one vote lost per failed route), FL drops the
+        missing clients from the FedAvg average (their whole round of
+        local work lost), SL carries the state through unchanged unless
+        every link delivered (the whole round lost).
 
         wire — the cut-layer link format (core/wirefmt.py): "dense" moves
         quantized values at their storage dtype (the golden baseline),
@@ -62,24 +76,6 @@ class Scheme:
         topology — the inference graph (core/topology.py); the default
         star keeps the pre-topology round bit for bit."""
         raise NotImplementedError
-
-    def make_transport_round(self, cfg, *, lr: float = 2e-3,
-                             wire: str = "dense", topology=None):
-        """Return round_fn(state, views, labels, rng, delivery) ->
-        (new_state, metrics): `make_round` with the fault outcome as an
-        EXPLICIT (J,) boolean argument instead of an in-graph draw.
-
-        `delivery` is the transport layer's measured verdict for this
-        round (repro/transport.NetworkTransport.round_outcome — retries,
-        circuit breakers and chaos already applied).  Each scheme applies
-        its own degradation semantics to the same mask: INL partial-fuses
-        the surviving views (one vote lost per failed route), FL drops the
-        missing clients from the FedAvg average (their whole round of
-        local work lost), SL carries the state through unchanged unless
-        every link delivered (the whole round lost) — the comparison the
-        chaos bench quantifies."""
-        raise NotImplementedError(f"scheme {self.name!r} has no "
-                                  "transport round")
 
     def make_sharded_round(self, cfg, mesh, *, lr: float = 2e-3,
                            wire: str = "dense", topology=None):

@@ -55,54 +55,33 @@ class FLScheme(base.Scheme):
         # (quantized FedAvg would be a different algorithm), so `wire` is
         # accepted for interface parity and ignored; the weight exchange is
         # a client<->server star by definition, so non-star topologies are
-        # rejected up front.  A star whose edges carry LinkModels (or
-        # cfg.edge_dropout > 0) IS accepted: dropped uplinks mask their
-        # client's weights out of the FedAvg average
-        # (core/linkfault.client_delivery_mask; all lost keeps the
-        # previous global model).
+        # rejected up front.  A (J,) delivery mask is the set of client
+        # uploads that ARRIVED: missing clients are dropped from the FedAvg
+        # average and their whole round of local work is lost (all lost
+        # keeps the previous global model).  Without one, a star whose
+        # edges carry LinkModels (or cfg.edge_dropout > 0) draws the mask
+        # in-graph (core/linkfault.client_delivery_mask).
         topology_lib.require_star(topology, cfg, scheme=self.name)
         topo_full = topology_lib.resolve(topology, cfg)
         faulty = linkfault.active(topo_full, cfg, train=True)
         opt = optim.adam(lr)
-        round_impl = fl.make_round(cfg, opt, self.local_steps, faulty=faulty)
         J, ls = cfg.num_clients, self.local_steps
+        round_impl = fl.make_round(cfg, opt, ls)
+        masked_impl = fl.make_round(cfg, opt, ls, faulty=True)
 
         @jax.jit
-        def round_fn(state, views, labels, rng):
+        def round_fn(state, views, labels, rng, delivery=None):
             packed, lab = _pack_exp2_views(views, labels, J, ls)
             rngs = jax.random.split(rng, J)
             args = (state["params"], state["state"], state["opt"],
                     packed, lab, rngs)
-            if faulty:
-                mask = linkfault.client_delivery_mask(rng, topo_full, cfg,
-                                                      train=True)
-                params, st, opt_state, metrics = round_impl(*args, mask)
-            else:
+            if delivery is None and faulty:
+                delivery = linkfault.client_delivery_mask(rng, topo_full, cfg,
+                                                          train=True)
+            if delivery is None:
                 params, st, opt_state, metrics = round_impl(*args)
-            return ({"params": params, "state": st, "opt": opt_state},
-                    metrics)
-        return round_fn
-
-    def make_transport_round(self, cfg, *, lr: float = 2e-3,
-                             wire: str = "dense", topology=None):
-        # FL under a transport: the (J,) delivery verdict is the set of
-        # client uploads that ARRIVED — missing clients are dropped from
-        # the FedAvg average and their whole round of local work is lost
-        # (all-lost keeps the previous global model).  The whole-round
-        # granularity is the FL half of the one-vote-vs-whole-round
-        # comparison the chaos bench quantifies.
-        topology_lib.require_star(topology, cfg, scheme=self.name)
-        opt = optim.adam(lr)
-        round_impl = fl.make_round(cfg, opt, self.local_steps, faulty=True)
-        J, ls = cfg.num_clients, self.local_steps
-
-        @jax.jit
-        def round_fn(state, views, labels, rng, delivery):
-            packed, lab = _pack_exp2_views(views, labels, J, ls)
-            rngs = jax.random.split(rng, J)
-            params, st, opt_state, metrics = round_impl(
-                state["params"], state["state"], state["opt"],
-                packed, lab, rngs, delivery)
+            else:
+                params, st, opt_state, metrics = masked_impl(*args, delivery)
             return ({"params": params, "state": st, "opt": opt_state},
                     metrics)
         return round_fn

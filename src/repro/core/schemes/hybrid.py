@@ -118,7 +118,8 @@ class HybridScheme(base.Scheme):
                    "branch_loss": branch_loss}
         return loss, (metrics, {"encoders": new_enc})
 
-    def _make_step(self, cfg, *, lr, wire, topology, explicit_delivery):
+    def make_round(self, cfg, *, lr: float = 2e-3, wire: str = "dense",
+                   topology=None):
         fl_clients(cfg)                      # validate the mode split early
         opt = optim.adam(lr)
         topo_full = topology_lib.resolve(topology, cfg)
@@ -135,57 +136,36 @@ class HybridScheme(base.Scheme):
                 r_dec, cfg, wire=wire, topo=topo, delivery=delivery)
             params, opt_state = opt.update(grads, state["opt"],
                                            state["params"])
-            if delivery is not None:
-                # FL skip semantics: a weight-mode client whose route died
-                # never reached the server — revert its per-client rows
-                # (encoder + branch head) to the stale server copy.  Cut
-                # clients keep local updates (their branch stays on-node).
-                revert = jnp.logical_and(~modes, ~delivery)
+            # FL skip semantics: a weight-mode client whose route died
+            # never reached the server — revert its per-client rows
+            # (encoder + branch head) to the stale server copy.  Cut
+            # clients keep local updates (their branch stays on-node).
+            revert = jnp.logical_and(~modes, ~delivery)
 
-                def keep(new, old):
-                    m = revert.reshape((revert.shape[0],)
-                                       + (1,) * (new.ndim - 1))
-                    return jnp.where(m, old, new)
+            def keep(new, old):
+                m = revert.reshape((revert.shape[0],)
+                                   + (1,) * (new.ndim - 1))
+                return jnp.where(m, old, new)
 
-                old = state["params"]
-                params = dict(params, encoders=jax.tree.map(
-                    keep, params["encoders"], old["encoders"]))
-                params["decoder"] = dict(
-                    params["decoder"], branch_heads=jax.tree.map(
-                        keep, params["decoder"]["branch_heads"],
-                        old["decoder"]["branch_heads"]))
+            old = state["params"]
+            params = dict(params, encoders=jax.tree.map(
+                keep, params["encoders"], old["encoders"]))
+            params["decoder"] = dict(
+                params["decoder"], branch_heads=jax.tree.map(
+                    keep, params["decoder"]["branch_heads"],
+                    old["decoder"]["branch_heads"]))
             return ({"params": params, "state": new_enc, "opt": opt_state,
                      "modes": modes}, metrics)
 
-        if explicit_delivery:
-            return step
-
-        def round_fn(state, views, labels, rng):
+        def round_fn(state, views, labels, rng, delivery=None):
             # all-ones as a RUNTIME argument, not a trace-time None, so
             # the no-fault and perfect-link cases share one jitted graph
             # (see splitfed.py: a constant mask constant-folds into
             # different last-ulp arithmetic)
-            delivery = linkfault.round_delivery_mask(
-                rng, topo_full, cfg, labels.shape[-1], train=True) \
-                if faulty else jnp.ones((cfg.num_clients,), bool)
-            return step(state, views, labels, rng, delivery)
-        return round_fn
-
-    def make_round(self, cfg, *, lr: float = 2e-3, wire: str = "dense",
-                   topology=None):
-        step = self._make_step(cfg, lr=lr, wire=wire, topology=topology,
-                               explicit_delivery=False)
-
-        def round_fn(state, views, labels, rng):
-            return step(state, views[0], labels[0], rng)
-        return round_fn
-
-    def make_transport_round(self, cfg, *, lr: float = 2e-3,
-                             wire: str = "dense", topology=None):
-        step = self._make_step(cfg, lr=lr, wire=wire, topology=topology,
-                               explicit_delivery=True)
-
-        def round_fn(state, views, labels, rng, delivery):
+            if delivery is None:
+                delivery = linkfault.round_delivery_mask(
+                    rng, topo_full, cfg, labels.shape[-1], train=True) \
+                    if faulty else jnp.ones((cfg.num_clients,), bool)
             return step(state, views[0], labels[0], rng, delivery)
         return round_fn
 

@@ -33,28 +33,14 @@ class INLScheme(base.Scheme):
 
     def make_round(self, cfg, *, lr: float = 2e-3, wire: str = "dense",
                    topology=None):
+        # a given (J,) delivery mask partial-fuses the surviving views
+        # (renormalised by J/n): a lost one costs exactly its own vote —
+        # rate terms and branch heads stay local, so a cut-off node keeps
+        # training its encoder
         opt = optim.adam(lr)
         step = inl.make_train_step(cfg, opt, wire=wire, topology=topology)
 
-        def round_fn(state, views, labels, rng):
-            params, st, opt_state, metrics = step(
-                state["params"], state["state"], state["opt"],
-                views[0], labels[0], rng)
-            return ({"params": params, "state": st, "opt": opt_state},
-                    metrics)
-        return round_fn
-
-    def make_transport_round(self, cfg, *, lr: float = 2e-3,
-                             wire: str = "dense", topology=None):
-        # the transport's measured (J,) outcome IS the round's delivery
-        # mask: surviving views partial-fuse (renormalised by J/n), lost
-        # ones cost exactly their own vote — rate terms and branch heads
-        # stay local, so a cut-off node keeps training its encoder
-        opt = optim.adam(lr)
-        step = inl.make_train_step(cfg, opt, wire=wire, topology=topology,
-                                   explicit_delivery=True)
-
-        def round_fn(state, views, labels, rng, delivery):
+        def round_fn(state, views, labels, rng, delivery=None):
             params, st, opt_state, metrics = step(
                 state["params"], state["state"], state["opt"],
                 views[0], labels[0], rng, delivery)

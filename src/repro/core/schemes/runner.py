@@ -5,10 +5,11 @@ interface; this module supplies the epoch pipeline, minibatch grouping, the
 BandwidthMeter, and the accuracy-vs-epoch / accuracy-vs-Gbit curve — so a
 newly registered scheme benchmarks itself with zero extra glue.
 
-Dispatch strategies (the perf ladder tests/benchmarks compare):
+ONE epoch loop serves every dispatch path: init, resume, the bandwidth
+charges, the round-key chain (`_split_chain` per epoch), the per-epoch
+index matrix, metering, evaluation, the curve and checkpoints are written
+once.  Only the body of an epoch differs:
 
-    "per_round"  the seed-style loop: one host->device transfer + one jitted
-                 dispatch per round (kept as the benchmark baseline);
     "scan"       the default: the view set is placed on the device once,
                  each epoch's (K, R, b) index matrix, labels and round keys
                  move through the double-buffered prefetcher
@@ -16,6 +17,12 @@ Dispatch strategies (the perf ladder tests/benchmarks compare):
                  the epoch's (K, R, J, b, ...) superbatch from the resident
                  set, and ONE jitted lax.scan (Scheme.make_epoch) runs it —
                  K rounds per dispatch instead of K dispatches.
+    "per_round"  for each round, a host gather of its minibatches and one
+                 jitted round dispatch; the reference the scan path is
+                 tested against, and the path for view sets larger than
+                 device memory.  A `transport` runs this path, its
+                 measured (J,) verdict passed to each round as the
+                 delivery mask.
 
 `mesh` (a ('client', 'data') mesh from launch.mesh.make_inl_host_mesh /
 make_inl_mesh) switches the scan body to the scheme's shard_map round
@@ -229,18 +236,20 @@ def run_scheme(name: str, views, labels, cfg, *, epochs: int,
     links (pass `meter=` a BandwidthMeter to read the per-edge ledgers
     afterwards).
 
+    One epoch loop serves every path; only an epoch's body differs.
     dispatch="scan" (default) runs each epoch as one jitted lax.scan.  It
     keeps the view set resident on the device (replicated over `mesh`) and
     gathers each epoch's superbatch there from the prefetched index matrix,
     so its device memory is the views' bytes plus one epoch superbatch;
-    dispatch="per_round" (the seed-style loop, one dispatch per round)
-    streams sets larger than that.  `mesh` enables shard_map execution (scan
-    dispatch only).  wire="packed" moves the cut-layer collectives as
-    bit-packed codewords (trajectories identical to dense);
-    "packed_duplex" packs the backward error vectors too.  topology — a
-    core/topology.Topology routing the INL exchange over a multi-hop graph
-    (the default star reproduces the pre-topology behaviour bit for bit;
-    FL/SL validate and reject non-star graphs).
+    dispatch="per_round" gathers each round's minibatches on the host and
+    dispatches one round at a time, which streams sets larger than that
+    and is the reference the scan path is tested against.  `mesh` enables
+    shard_map execution (scan dispatch only).  wire="packed" moves the
+    cut-layer collectives as bit-packed codewords (trajectories identical
+    to dense); "packed_duplex" packs the backward error vectors too.
+    topology — a core/topology.Topology routing the INL exchange over a
+    multi-hop graph (the default star reproduces the pre-topology
+    behaviour bit for bit; FL/SL validate and reject non-star graphs).
 
     Elastic recovery: `ckpt_dir` saves an epoch-granular checkpoint every
     `ckpt_every` epochs (full state + curve + meter ledgers);
@@ -249,11 +258,15 @@ def run_scheme(name: str, views, labels, cfg, *, epochs: int,
     uninterrupted run (tests/test_recovery.py pins it).
 
     transport — a repro/transport.NetworkTransport over the resolved
-    topology: fault outcomes then come from the transport's retrying
-    channels / breakers / chaos schedule per round instead of in-graph
-    draws (Scheme.make_transport_round), metered on the transport's
-    offered/delivered ledgers.  Transport execution is per-round
-    (host-side masks), so it excludes mesh/scan dispatch.
+    topology.  Each round then calls `transport.round_outcome(tick, ...)`:
+    the retrying channels, circuit breakers and chaos schedule decide the
+    (J,) delivery mask, which reaches the round as its `delivery`
+    argument instead of an in-graph draw.  The transport owns the run's
+    meter: offered accrues per attempt, delivered per surviving payload
+    fraction.  Its masks are host-side, so a transport runs per round and
+    excludes a mesh; a resume replays the completed ticks with
+    ``charge=False``, which reproduces the breaker trajectories without
+    re-charging the restored ledgers.
     """
     from repro.core import schemes
     scheme = schemes.get(name)
@@ -263,68 +276,94 @@ def run_scheme(name: str, views, labels, cfg, *, epochs: int,
         if meter is not None and meter is not transport.meter:
             raise ValueError("pass either meter= or transport= (the "
                              "transport owns the run's meter)")
-        return _run_transport(scheme, views, labels, cfg, epochs=epochs,
-                              batch_size=batch_size, lr=lr, seed=seed,
-                              eval_n=eval_n, wire=wire, topology=topology,
-                              transport=transport, ckpt_dir=ckpt_dir,
-                              ckpt_every=ckpt_every, resume=resume)
-    if dispatch == "per_round":
+        meter = transport.meter
+    elif dispatch == "per_round":
         if mesh is not None:
             raise ValueError("mesh execution needs dispatch='scan'")
-        return _run_per_round(scheme, views, labels, cfg, epochs=epochs,
-                              batch_size=batch_size, lr=lr, seed=seed,
-                              eval_n=eval_n, wire=wire, topology=topology,
-                              meter=meter, ckpt_dir=ckpt_dir,
-                              ckpt_every=ckpt_every, resume=resume)
-    if dispatch != "scan":
+    elif dispatch != "scan":
         raise ValueError(f"unknown dispatch {dispatch!r}")
+    per_round = transport is not None or dispatch == "per_round"
 
     state = scheme.init(cfg, jax.random.PRNGKey(seed), lr=lr)
-    epoch_fn = scheme.make_epoch(cfg, lr=lr, mesh=mesh, wire=wire,
-                                 topology=topology)
+    if per_round:
+        round_fn = scheme.make_round(cfg, lr=lr, wire=wire,
+                                     topology=topology)
+    else:
+        epoch_fn = scheme.make_epoch(cfg, lr=lr, mesh=mesh, wire=wire,
+                                     topology=topology)
     bpr = scheme.batches_per_round(cfg)
     labels_np = np.asarray(labels)
     n = labels_np.shape[0]
     rounds = rounds_per_epoch(scheme, cfg, n, batch_size)
     n_eval = min(eval_n, n)
 
-    with tracing.span("runner.resident") as sp:
-        resident, ev = _resident(views, n_eval)
+    item_shardings = None
+    if per_round:
+        views_np = np.asarray(views)
+        ev = jnp.asarray(views_np[:, :n_eval])
+    else:
+        with tracing.span("runner.resident") as sp:
+            resident, ev = _resident(views, n_eval)
+            if mesh is not None:
+                resident = jax.device_put(resident,
+                                          NamedSharding(mesh, P()))
+            sp.set_metadata(bytes=resident.nbytes)
+        # one epoch's (K, R, J, b, ...) superbatch, in bytes
+        superbatch_bytes = resident.nbytes // resident.shape[1] * (
+            rounds * bpr * batch_size)
+        xs_shardings = None
         if mesh is not None:
-            resident = jax.device_put(resident, NamedSharding(mesh, P()))
-        sp.set_metadata(bytes=resident.nbytes)
-    # one epoch's (K, R, J, b, ...) superbatch, in bytes
-    superbatch_bytes = resident.nbytes // resident.shape[1] * (
-        rounds * bpr * batch_size)
-
-    xs_shardings = item_shardings = None
-    if mesh is not None:
-        from repro.launch import sharding as sharding_lib
-        state = jax.device_put(state,
-                               scheme.state_shardings(cfg, state, mesh))
-        xs_shardings = sharding_lib.scheme_batch_shardings(
-            mesh, cfg.num_clients, batch_size)
-        # the index matrix is laid out as the labels are
-        item_shardings = (xs_shardings[1],) + xs_shardings[1:]
-    gather = jax.jit(_superbatch, static_argnames="image_shape",
-                     out_shardings=None if mesh is None else xs_shardings[0])
-    image_shape = tuple(views.shape[2:])
+            from repro.launch import sharding as sharding_lib
+            state = jax.device_put(state,
+                                   scheme.state_shardings(cfg, state, mesh))
+            xs_shardings = sharding_lib.scheme_batch_shardings(
+                mesh, cfg.num_clients, batch_size)
+            # the index matrix is laid out as the labels are
+            item_shardings = (xs_shardings[1],) + xs_shardings[1:]
+        gather = jax.jit(_superbatch, static_argnames="image_shape",
+                         out_shardings=None if mesh is None
+                         else xs_shardings[0])
+        image_shape = tuple(views.shape[2:])
 
     meter = bandwidth.BandwidthMeter() if meter is None else meter
-    start_ep = 0
+    start_ep, curve0, tsnap = 0, [], None
     if resume and ckpt_dir:
-        state, curve0, start_ep, _ = _try_resume(ckpt_dir, state, meter)
+        state, curve0, start_ep, tsnap = _try_resume(ckpt_dir, state, meter)
         if mesh is not None and start_ep:
             state = jax.device_put(state,
                                    scheme.state_shardings(cfg, state, mesh))
-    else:
-        curve0 = []
+
+    charges = _round_charges(scheme, cfg, state, batch_size, wire=wire,
+                             topology=topology)
+    topo_full = topology_lib.resolve(topology, cfg)
+    faulty = linkfault.active(topo_full, cfg, train=True)
+    tick = start_ep * rounds
+    if transport is not None:
+        if set(charges) == {None}:
+            # scalar totals (FL/SL): split the round's charge equally
+            # across the (star) edges so per-edge attempts re-offer their
+            # own share
+            edges = transport.topo.edges
+            b, nb = charges[None]
+            charges = {e.key: (b / len(edges), nb / len(edges))
+                       for e in edges}
+        for t in range(tick):                 # breaker replay, ledger-free
+            transport.round_outcome(t, batch_size, charges=charges,
+                                    charge=False)
+        if tsnap is not None:
+            # the replay above already reproduced the adaptive knob
+            # trajectory (observe runs on uncharged rounds too); loading
+            # the sidecar's copy on top makes the checkpoint authoritative
+            # over the replay
+            transport.load_snapshot(tsnap)
 
     def epoch_items():
         """(index matrix (K,R,b), labels (K,R,b), rngs (K,2)) per epoch —
         what the device needs to gather the epoch's superbatch from the
-        resident views; the prefetcher moves it while the previous epoch
-        computes.  A resumed run fast-forwards the rng chain through the
+        resident views (the prefetcher moves it while the previous epoch
+        computes), or what the per-round body gathers on the host.  The
+        round keys are the exact chain repeated jax.random.split(rng)
+        gives.  A resumed run fast-forwards the rng chain through the
         completed epochs WITHOUT assembling their items — the downstream
         subkeys (and so the trajectory) are exactly the uninterrupted
         run's."""
@@ -342,181 +381,51 @@ def run_scheme(name: str, views, labels, cfg, *, epochs: int,
                 sp.set_metadata(bytes=prefetch.nbytes(item))
             yield item
 
-    charges = _round_charges(scheme, cfg, state, batch_size, wire=wire,
-                             topology=topology)
-    topo_full = topology_lib.resolve(topology, cfg)
-    faulty = linkfault.active(topo_full, cfg, train=True)
+    items = epoch_items() if rounds else iter(())
+    if not per_round:
+        items = prefetch.prefetch_to_device(items, size=prefetch_size,
+                                            shardings=item_shardings)
     el = jnp.asarray(labels_np[:n_eval])
 
     curve: List[CurvePoint] = list(curve0)
-    items = prefetch.prefetch_to_device(
-        epoch_items() if rounds else iter(()), size=prefetch_size,
-        shardings=item_shardings)
     for ep in range(start_ep, epochs):
         if rounds:
             ep_idx, ep_labels, ep_rngs = next(items)
-            with tracing.span("runner.gather", rounds=rounds,
-                              bytes=superbatch_bytes):
-                ep_views = gather(resident, ep_idx, image_shape=image_shape)
-            state, _ = epoch_fn(state, ep_views, ep_labels, ep_rngs)
-            # freed once the epoch program is done with it, so the next
-            # gather finds at most this one superbatch beside the views
-            del ep_views
-            if faulty:
-                # the scan's per-round subkeys ARE the round rngs — replay
-                # their folded fault draws host-side for the two ledgers
-                _meter_fault_rounds(meter, scheme, topo_full, cfg,
-                                    batch_size, charges,
-                                    jax.device_get(ep_rngs))
+            if per_round:
+                for r in range(rounds):
+                    delivery = None
+                    if transport is not None:
+                        delivery = jnp.asarray(transport.round_outcome(
+                            tick, batch_size, charges=charges).mask)
+                        tick += 1
+                    # the round's (R, J, b, ...) minibatches
+                    v = np.moveaxis(views_np[:, ep_idx[r]], 0, 1)
+                    state, _ = round_fn(state, jnp.asarray(v),
+                                        jnp.asarray(ep_labels[r]),
+                                        ep_rngs[r], delivery)
             else:
-                _meter_rounds(meter, charges, rounds)
+                with tracing.span("runner.gather", rounds=rounds,
+                                  bytes=superbatch_bytes):
+                    ep_views = gather(resident, ep_idx,
+                                      image_shape=image_shape)
+                state, _ = epoch_fn(state, ep_views, ep_labels, ep_rngs)
+                # freed once the epoch program is done with it, so the
+                # next gather finds at most this one superbatch beside the
+                # views
+                del ep_views
+            # a transport has charged its rounds in round_outcome
+            if transport is None:
+                if faulty:
+                    # the per-round subkeys ARE the round rngs — replay
+                    # their folded fault draws host-side for the two ledgers
+                    _meter_fault_rounds(meter, scheme, topo_full, cfg,
+                                        batch_size, charges,
+                                        jax.device_get(ep_rngs))
+                else:
+                    _meter_rounds(meter, charges, rounds)
         _meter_overheads(meter, scheme, cfg, state)
         eval_state = jax.device_get(state) if mesh is not None else state
         acc = base.evaluate_accuracy(scheme, eval_state, ev, el,
-                                     topology=topology, cfg=cfg)
-        curve.append(CurvePoint(ep + 1, acc, meter.gbits,
-                                meter.measured_gbits, meter.delivered_gbits))
-        if ckpt_dir and ((ep + 1) % max(ckpt_every, 1) == 0
-                         or ep + 1 == epochs):
-            _save_epoch(ckpt_dir, scheme.name, ep + 1, state, curve, meter)
-    return curve
-
-
-def _run_per_round(scheme, views, labels, cfg, *, epochs, batch_size, lr,
-                   seed, eval_n, wire="dense", topology=None, meter=None,
-                   ckpt_dir=None, ckpt_every: int = 1, resume: bool = False):
-    """The seed-style path: one transfer + one jitted dispatch per round.
-    Kept verbatim as the semantics reference the scan path is tested
-    against."""
-    state = scheme.init(cfg, jax.random.PRNGKey(seed), lr=lr)
-    round_fn = scheme.make_round(cfg, lr=lr, wire=wire, topology=topology)
-    bpr = scheme.batches_per_round(cfg)
-
-    meter = bandwidth.BandwidthMeter() if meter is None else meter
-    start_ep = 0
-    if resume and ckpt_dir:
-        state, curve0, start_ep, _ = _try_resume(ckpt_dir, state, meter)
-    else:
-        curve0 = []
-    charges = _round_charges(scheme, cfg, state, batch_size, wire=wire,
-                             topology=topology)
-    topo_full = topology_lib.resolve(topology, cfg)
-    faulty = linkfault.active(topo_full, cfg, train=True)
-    rounds = rounds_per_epoch(scheme, cfg, labels.shape[0], batch_size)
-    rng = jax.random.PRNGKey(seed + 1)
-    if start_ep and rounds:
-        # replay the completed epochs' split chain so the next subkey (and
-        # the trajectory downstream of it) matches the uninterrupted run
-        rng, _ = _split_chain(rng, start_ep * rounds)
-    n_eval = min(eval_n, labels.shape[0])
-    ev = jnp.asarray(views[:, :n_eval])
-    el = jnp.asarray(labels[:n_eval])
-
-    curve: List[CurvePoint] = list(curve0)
-    for ep in range(start_ep, epochs):
-        group_v, group_l = [], []
-        for v, l in multiview.multiview_batches(views, labels, batch_size,
-                                                seed=ep):
-            group_v.append(v)
-            group_l.append(l)
-            if len(group_v) < bpr:
-                continue
-            rng, sub = jax.random.split(rng)
-            state, metrics = round_fn(
-                state, jnp.asarray(np.stack(group_v)),
-                jnp.asarray(np.stack(group_l)), sub)
-            if faulty:
-                _meter_fault_rounds(meter, scheme, topo_full, cfg,
-                                    batch_size, charges, [sub])
-            else:
-                _meter_rounds(meter, charges)
-            group_v, group_l = [], []
-        _meter_overheads(meter, scheme, cfg, state)
-        acc = base.evaluate_accuracy(scheme, state, ev, el,
-                                     topology=topology, cfg=cfg)
-        curve.append(CurvePoint(ep + 1, acc, meter.gbits,
-                                meter.measured_gbits, meter.delivered_gbits))
-        if ckpt_dir and ((ep + 1) % max(ckpt_every, 1) == 0
-                         or ep + 1 == epochs):
-            _save_epoch(ckpt_dir, scheme.name, ep + 1, state, curve, meter)
-    return curve
-
-
-def _run_transport(scheme, views, labels, cfg, *, epochs, batch_size, lr,
-                   seed, eval_n, wire="dense", topology=None, transport=None,
-                   ckpt_dir=None, ckpt_every: int = 1, resume: bool = False):
-    """Per-round execution where fault outcomes come from the TRANSPORT:
-    each round calls `transport.round_outcome(tick, ...)` — the retrying
-    channels, circuit breakers, and chaos schedule decide the (J,) delivery
-    mask — and hands the host-side verdict to the scheme's
-    `make_transport_round` round (explicit delivery, no in-graph draws).
-    The transport owns the run's meter: offered accrues per attempt,
-    delivered per surviving payload fraction.
-
-    Degradation semantics (the chaos bench's comparison): INL partial-fuses
-    the surviving views (one vote lost per failed route), FL drops missing
-    clients from the FedAvg average (their whole round of local work lost),
-    SL skips the whole round unless every link delivered.
-
-    A resume replays the completed ticks with ``charge=False`` — the breaker
-    trajectories are reproduced without re-charging the restored ledgers —
-    so the resumed run is bit-identical to the uninterrupted one."""
-    state = scheme.init(cfg, jax.random.PRNGKey(seed), lr=lr)
-    round_fn = scheme.make_transport_round(cfg, lr=lr, wire=wire,
-                                           topology=topology)
-    bpr = scheme.batches_per_round(cfg)
-    meter = transport.meter
-    charges = _round_charges(scheme, cfg, state, batch_size, wire=wire,
-                             topology=topology)
-    edges = transport.topo.edges
-    if set(charges) == {None}:
-        # scalar totals (FL/SL): split the round's charge equally across
-        # the (star) edges so per-edge attempts re-offer their own share
-        b, nb = charges[None]
-        charges = {e.key: (b / len(edges), nb / len(edges)) for e in edges}
-    rounds = rounds_per_epoch(scheme, cfg, labels.shape[0], batch_size)
-
-    start_ep = 0
-    tsnap = None
-    if resume and ckpt_dir:
-        state, curve0, start_ep, tsnap = _try_resume(ckpt_dir, state, meter)
-    else:
-        curve0 = []
-    rng = jax.random.PRNGKey(seed + 1)
-    tick = start_ep * rounds
-    if tick:
-        rng, _ = _split_chain(rng, tick)
-        for t in range(tick):                 # breaker replay, ledger-free
-            transport.round_outcome(t, batch_size, charges=charges,
-                                    charge=False)
-    if tsnap is not None:
-        # the replay above already reproduced the adaptive knob trajectory
-        # (observe runs on uncharged rounds too); loading the sidecar's
-        # copy on top makes the checkpoint authoritative over the replay
-        transport.load_snapshot(tsnap)
-
-    n_eval = min(eval_n, labels.shape[0])
-    ev = jnp.asarray(views[:, :n_eval])
-    el = jnp.asarray(labels[:n_eval])
-
-    curve: List[CurvePoint] = list(curve0)
-    for ep in range(start_ep, epochs):
-        group_v, group_l = [], []
-        for v, l in multiview.multiview_batches(views, labels, batch_size,
-                                                seed=ep):
-            group_v.append(v)
-            group_l.append(l)
-            if len(group_v) < bpr:
-                continue
-            rng, sub = jax.random.split(rng)
-            rep = transport.round_outcome(tick, batch_size, charges=charges)
-            tick += 1
-            state, metrics = round_fn(
-                state, jnp.asarray(np.stack(group_v)),
-                jnp.asarray(np.stack(group_l)), sub, jnp.asarray(rep.mask))
-            group_v, group_l = [], []
-        _meter_overheads(meter, scheme, cfg, state)
-        acc = base.evaluate_accuracy(scheme, state, ev, el,
                                      topology=topology, cfg=cfg)
         curve.append(CurvePoint(ep + 1, acc, meter.gbits,
                                 meter.measured_gbits, meter.delivered_gbits))

@@ -35,27 +35,36 @@ class SLScheme(base.Scheme):
                 "opt_c": oc.init(client), "opt_s": osrv.init(server)}
 
     def _skip_failed_round(self, cfg, topology, round_fn):
-        """Wrap a round: when the (star) topology models unreliable links,
-        draw the bounded-retry survival from the round rng and carry the
-        state through UNCHANGED on total failure.  A perfect link draws
-        success with certainty, so jnp.where(True, new, old) keeps the
-        legacy trajectory bitwise."""
+        """Wrap a round with SL's whole-round skip: the exchange rides ONE
+        client->server boundary, so it has no partial reading.  A given
+        (J,) delivery mask lets the round run iff every link delivered
+        (the transport already spent the retry budget); without one, a
+        star that models unreliable links draws the bounded-retry survival
+        from the round rng.  On failure the state carries through
+        UNCHANGED and the whole round is lost."""
         import jax.numpy as jnp
         topo_full = topology_lib.resolve(topology, cfg)
-        if not linkfault.active(topo_full, cfg, train=True):
-            return round_fn
+        faulty = linkfault.active(topo_full, cfg, train=True)
         attempts = self.max_link_retries + 1
 
-        def faulty_round(state, views, labels, rng):
+        def skipping_round(state, views, labels, rng, delivery=None):
             new_state, metrics = round_fn(state, views, labels, rng)
-            ok = linkfault.round_success(rng, topo_full, cfg, attempts)
+            if delivery is not None:
+                ok = jnp.all(delivery)
+            elif faulty:
+                ok = linkfault.round_success(rng, topo_full, cfg, attempts)
+            else:
+                return new_state, metrics
             new_state = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
                                      new_state, state)
             return new_state, metrics
-        return faulty_round
+        return skipping_round
 
-    def _make_raw_round(self, cfg, *, lr: float, wire: str):
-        """The fault-free round body (no link-survival wrapper)."""
+    def make_round(self, cfg, *, lr: float = 2e-3, wire: str = "dense",
+                   topology=None):
+        # SL's cut is ONE client->server boundary (all conv branches live on
+        # the active client), so only the star topology has a reading here
+        topology_lib.require_star(topology, cfg, scheme=self.name)
         oc, osrv = optim.adam(lr), optim.adam(lr)
         step = sl.make_train_step(
             oc, osrv, link_bits=cfg.link_bits, wire=wire,
@@ -67,35 +76,7 @@ class SLScheme(base.Scheme):
                 state["opt_c"], state["opt_s"], views[0], labels[0], rng)
             return ({"client": client, "server": server, "state": st,
                      "opt_c": opt_c, "opt_s": opt_s}, metrics)
-        return round_fn
-
-    def make_round(self, cfg, *, lr: float = 2e-3, wire: str = "dense",
-                   topology=None):
-        # SL's cut is ONE client->server boundary (all conv branches live on
-        # the active client), so only the star topology has a reading here
-        topology_lib.require_star(topology, cfg, scheme=self.name)
-        return self._skip_failed_round(
-            cfg, topology, self._make_raw_round(cfg, lr=lr, wire=wire))
-
-    def make_transport_round(self, cfg, *, lr: float = 2e-3,
-                             wire: str = "dense", topology=None):
-        # SL under a transport: the round's exchange rides the single
-        # client->server boundary, so it has no partial reading — the round
-        # RUNS iff every link delivered (the transport already spent the
-        # retry budget), else the state carries through unchanged and the
-        # whole round is lost.  The SL half of the one-vote-vs-whole-round
-        # comparison.
-        import jax.numpy as jnp
-        topology_lib.require_star(topology, cfg, scheme=self.name)
-        inner = self._make_raw_round(cfg, lr=lr, wire=wire)
-
-        def round_fn(state, views, labels, rng, delivery):
-            new_state, metrics = inner(state, views, labels, rng)
-            ok = jnp.all(delivery)
-            new_state = jax.tree.map(lambda n, o: jnp.where(ok, n, o),
-                                     new_state, state)
-            return new_state, metrics
-        return round_fn
+        return self._skip_failed_round(cfg, topology, round_fn)
 
     def make_sharded_round(self, cfg, mesh, *, lr: float = 2e-3,
                            wire: str = "dense", topology=None):

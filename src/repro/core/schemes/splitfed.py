@@ -121,8 +121,7 @@ class SplitFedScheme(base.Scheme):
             _, _, u_joint = topology_lib.graph_cut_and_ship(
                 topo, cfg, mu, logvar, jnp.zeros(mu.shape, jnp.float32),
                 rate_estimator="none", wire=wire)
-        if delivery is not None:
-            u_joint = linkfault.partial_fuse(u_joint, delivery)
+        u_joint = linkfault.partial_fuse(u_joint, delivery)
         logits = paper_model.decoder_apply(params_c["decoder"],
                                            _fuse_cat(u_joint), train=True,
                                            rng=rng)
@@ -130,7 +129,11 @@ class SplitFedScheme(base.Scheme):
         metrics = {"loss": loss, "accuracy": losses.accuracy(logits, labels)}
         return loss, (metrics, {"encoders": new_enc})
 
-    def _make_step(self, cfg, *, lr, wire, topology, explicit_delivery):
+    def make_round(self, cfg, *, lr: float = 2e-3, wire: str = "dense",
+                   topology=None):
+        # a (J,) delivery mask masks BOTH of the round's exchanges: a dead
+        # route's activations leave the fusion AND its weights leave the
+        # average — one fault, two degradations
         opt = optim.adam(lr)
         topo_full = topology_lib.resolve(topology, cfg)
         topo = topology_lib.nontrivial(topology, cfg)
@@ -145,17 +148,12 @@ class SplitFedScheme(base.Scheme):
                 wire=wire, topo=topo, delivery=delivery)
             params, opt_state = opt.update(grads, state["opt"],
                                            state["params"])
-            mask = jnp.ones((cfg.num_clients,), bool) if delivery is None \
-                else delivery
             params = dict(params, encoders=fedavg(
-                params["encoders"], state["params"]["encoders"], mask))
+                params["encoders"], state["params"]["encoders"], delivery))
             return ({"params": params, "state": new_enc, "opt": opt_state},
                     metrics)
 
-        if explicit_delivery:
-            return step
-
-        def round_fn(state, views, labels, rng):
+        def round_fn(state, views, labels, rng, delivery=None):
             # the fault stream folds off rng (linkfault.fault_key) without
             # disturbing the round's own key consumption.  The no-fault
             # path ships an all-ones mask as a RUNTIME argument rather
@@ -164,30 +162,10 @@ class SplitFedScheme(base.Scheme):
             # division than the traced graph uses, so the two spellings
             # would differ in the last ulp — one traced graph keeps
             # perfect links bitwise identical to the fault-free run
-            delivery = linkfault.round_delivery_mask(
-                rng, topo_full, cfg, labels.shape[-1], train=True) \
-                if faulty else jnp.ones((cfg.num_clients,), bool)
-            return step(state, views, labels, rng, delivery)
-        return round_fn
-
-    def make_round(self, cfg, *, lr: float = 2e-3, wire: str = "dense",
-                   topology=None):
-        step = self._make_step(cfg, lr=lr, wire=wire, topology=topology,
-                               explicit_delivery=False)
-
-        def round_fn(state, views, labels, rng):
-            return step(state, views[0], labels[0], rng)
-        return round_fn
-
-    def make_transport_round(self, cfg, *, lr: float = 2e-3,
-                             wire: str = "dense", topology=None):
-        # the transport's measured (J,) outcome masks BOTH of the round's
-        # exchanges: a dead route's activations leave the fusion AND its
-        # weights leave the average — one fault, two degradations
-        step = self._make_step(cfg, lr=lr, wire=wire, topology=topology,
-                               explicit_delivery=True)
-
-        def round_fn(state, views, labels, rng, delivery):
+            if delivery is None:
+                delivery = linkfault.round_delivery_mask(
+                    rng, topo_full, cfg, labels.shape[-1], train=True) \
+                    if faulty else jnp.ones((cfg.num_clients,), bool)
             return step(state, views[0], labels[0], rng, delivery)
         return round_fn
 

@@ -98,7 +98,7 @@ def test_transport_round_semantics_partial_delivery():
     def moved(scheme_name, bpr_views):
         scheme = schemes.get(scheme_name)
         state = scheme.init(CFG, jax.random.PRNGKey(0))
-        new, _ = scheme.make_transport_round(CFG)(
+        new, _ = scheme.make_round(CFG)(
             state, bpr_views[0], bpr_views[1], rng, delivery)
         return any(not np.array_equal(a, b) for a, b in
                    zip(jax.tree.leaves(jax.device_get(new)),
@@ -126,7 +126,7 @@ def test_transport_round_all_lost_keeps_state():
     v = jnp.broadcast_to(views[:, :32][None], (R, J, 32) + views.shape[2:])
     l = jnp.broadcast_to(labels[:32][None], (R, 32))
     state = fl.init(CFG, jax.random.PRNGKey(0))
-    new, _ = fl.make_transport_round(CFG)(state, v, l, rng, none)
+    new, _ = fl.make_round(CFG)(state, v, l, rng, none)
     assert all(np.array_equal(a, b) for a, b in
                zip(jax.tree.leaves(jax.device_get(new["params"])),
                    jax.tree.leaves(jax.device_get(state["params"]))))

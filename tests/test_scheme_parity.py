@@ -3,6 +3,8 @@ Scheme contract on the same fixture —
 
   * a jitted round on a fixed seed improves the training loss,
   * `predict` returns a probability distribution (rows sum to 1),
+  * a round given an all-delivered (J,) mask equals the round that draws
+    its own on a clean network,
   * `bits_per_round` agrees EXACTLY with the closed-form §III-C / Table-I
     accounting in core/bandwidth.py (and, for INL, with the bits the train
     step itself meters),
@@ -17,7 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _schemes_common import BATCH, CFG, fixture_data, trajectory
+from _schemes_common import (BATCH, CFG, fixture_data, round_inputs,
+                             trajectory)
 
 from repro.core import bandwidth, inl, paper_model, schemes
 
@@ -47,6 +50,28 @@ def test_predict_is_a_distribution(name):
     np.testing.assert_allclose(np.asarray(probs.sum(axis=-1)), 1.0,
                                atol=1e-5)
     assert float(probs.min()) >= 0.0
+
+
+@pytest.mark.parametrize("name", schemes.available())
+def test_round_with_all_delivered_mask_equals_clean_round(name):
+    """make_round's one round_fn: a (J,) all-ones delivery mask given as
+    data (the transport's verdict) lands where the clean round does."""
+    views, labels = fixture_data()
+    scheme = schemes.get(name)
+    state = scheme.init(CFG, jax.random.PRNGKey(0))
+    v, lab = round_inputs(scheme, CFG, views, labels)
+    round_fn = scheme.make_round(CFG)
+    rng = jax.random.PRNGKey(5)
+    want_state, want = round_fn(state, v, lab, rng)
+    got_state, got = round_fn(state, v, lab, rng,
+                              jnp.ones(CFG.num_clients, bool))
+    np.testing.assert_allclose(float(got["loss"]), float(want["loss"]),
+                               rtol=1e-6)
+    want_leaves = jax.tree.leaves(jax.device_get(want_state))
+    got_leaves = jax.tree.leaves(jax.device_get(got_state))
+    assert len(got_leaves) == len(want_leaves)
+    for g, w in zip(got_leaves, want_leaves):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6)
 
 
 def test_bits_per_round_match_table1_closed_forms():
